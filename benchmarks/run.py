@@ -34,6 +34,8 @@ TABLES = {
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = sys.argv[1:] or list(TABLES)
     print("name,us_per_call,derived")
     failures = 0

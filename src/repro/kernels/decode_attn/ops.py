@@ -11,8 +11,9 @@ offset masking (docs/DESIGN.md §11). ``k``/``v`` may be:
 
 Backend selection mirrors ``qdot`` (kernels/qmatmul/ops.py):
 
-* ``auto``    — (default) the Pallas kernel on TPU, else the ``grouped``
-  jnp fallback. Both stream the cache in KV chunks with an online softmax
+* ``auto``    — (default) the Pallas kernel on TPU in an unsharded
+  trace, else the ``grouped`` jnp fallback (the compiler cannot partition
+  a Pallas call over a mesh). Both stream the cache in KV chunks with an online softmax
   and dequantize in-register — no (…, S_max) score tensor is ever
   materialized on the decode path.
 * ``pallas``  — force the Pallas kernel (raises off-TPU rather than
@@ -76,18 +77,8 @@ def configure_decode_attn(backend: Optional[str] = None,
         _kv_chunk = kv_chunk
 
 
-def set_decode_attn_backend(name: str) -> None:
-    """Back-compat alias for ``configure_decode_attn(backend=...)``."""
-    configure_decode_attn(backend=name)
-
-
 def get_decode_attn_backend() -> str:
     return _backend
-
-
-def set_decode_kv_chunk(n: int) -> None:
-    """Back-compat alias for ``configure_decode_attn(kv_chunk=...)``."""
-    configure_decode_attn(kv_chunk=n)
 
 
 def get_decode_kv_chunk() -> int:
@@ -95,7 +86,10 @@ def get_decode_kv_chunk() -> int:
 
 
 def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
+    """A TPU, and a trace that is not partitioned over several devices
+    (the compiler refuses to partition a Mosaic kernel)."""
+    from repro.sharding.ctx import sharded_trace
+    return jax.default_backend() == "tpu" and not sharded_trace()
 
 
 def _page_of(x):
@@ -327,8 +321,9 @@ def decode_attention(q: jax.Array, k, v, *,
     if backend == "pallas" or (backend == "auto" and _use_pallas()):
         if backend == "pallas" and not _use_pallas():
             raise ValueError(
-                f"decode-attn backend 'pallas' needs a TPU; running on "
-                f"{jax.default_backend()!r} (use 'grouped' for the "
+                f"decode-attn backend 'pallas' needs a TPU and an "
+                f"unsharded trace; running on {jax.default_backend()!r} "
+                f"(use 'grouped' for the "
                 f"identical-math jnp fallback)")
         return _pallas(q, kp, vp, valid, kv_chunk, causal, fresh_kv)
     if backend == "simple":

@@ -9,7 +9,8 @@ weight matmul. ``w`` may be:
 Backend selection (explicit, per-call or process-wide):
 
 * ``auto``    — (default) the Pallas kernel on TPU when shapes are tile
-  aligned, else the ``simple`` jnp fallback. XLA fuses the fallback
+  aligned and the trace is not sharded over a mesh (the compiler cannot
+  partition a Pallas call), else the ``simple`` jnp fallback. XLA fuses the fallback
   reasonably, keeping HLO byte counts faithful to weight-only quantization
   (int8/int4 weights are read at their quantized width; dequant is a
   flop-cheap broadcast-multiply).
@@ -95,7 +96,10 @@ def get_qdot_backend() -> str:
 
 
 def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
+    """A TPU, and a trace that is not partitioned over several devices
+    (the compiler refuses to partition a Mosaic kernel)."""
+    from repro.sharding.ctx import sharded_trace
+    return jax.default_backend() == "tpu" and not sharded_trace()
 
 
 def _pallas_aligned(m: int, n: int, k: int, precision: str = "int8") -> bool:

@@ -28,6 +28,14 @@ dense engine:
       --num-requests 8 --paged --page-size 8 --shared-prefix-len 8 \
       --check-paged-parity
 
+Random weights at published width, no training (``--train-steps 0``):
+the model is initialized from ``--seed`` under ``jit`` — with ``--mesh``
+straight into its serving shardings — and no optimizer state is built.
+``--num-layers`` cuts the depth of the published config:
+  python -m repro.launch.serve --arch yi-9b --train-steps 0 \
+      --num-layers 12 --fast --variant 4bit/8bit --kv-precision int8 \
+      --num-requests 16 --num-slots 8 --prompt-len 256 --max-new 48
+
 Self-speculative decoding (docs/DESIGN.md §11): ``--spec-k 4`` serves with
 draft-propose/verify rounds — the entropy-ordered all-int4 draft shares
 payloads with the target; ``--check-greedy-parity`` additionally runs the
@@ -38,6 +46,7 @@ output (the CI anchor).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -45,13 +54,43 @@ import jax.numpy as jnp
 
 from repro.configs.base import RunConfig
 from repro.configs.registry import ARCHS, get_config
+from repro.launch.compile_cache import (enable_compile_cache,
+                                         keep_submesh_programs_out)
+from repro.models.model import build
 from repro.serving.engine import ServeEngine
 from repro.serving.quantized import plan_for_variant
 from repro.serving.scheduler import synthetic_stream
 from repro.train.loop import train
 
 
-def main():
+def init_params(model, seed: int, mesh=None):
+    """Random weights from ``seed``, built on device under ``jit``. With a
+    mesh the init writes straight into the TP serving shardings, so no
+    device ever holds the whole raw model."""
+    key = jax.random.PRNGKey(seed)
+    if mesh is None:
+        return jax.jit(model.init)(key)
+    from repro.sharding.specs import serving_param_shardings
+    shardings = serving_param_shardings(jax.eval_shape(model.init, key),
+                                        mesh)
+    return jax.jit(model.init, out_shardings=shardings)(key)
+
+
+def print_device_memory(stage: str) -> None:
+    """Device 0's bytes in use and its high-water mark so far, where the
+    backend reports them (TPU; the CPU backend does not)."""
+    stats = jax.devices()[0].memory_stats()
+    if stats and "bytes_in_use" in stats:
+        print(f"device 0 memory after {stage}: "
+              f"{stats['bytes_in_use'] / 2**30:.2f} GiB in use, peak "
+              f"{stats.get('peak_bytes_in_use', 0) / 2**30:.2f} GiB")
+
+
+def main(argv=None) -> dict:
+    """Run the launcher on ``argv`` (default: the command line). Returns
+    what was served — ``model``, ``engine``, ``plan``, ``requests`` and
+    ``outputs``/``stats`` for a stream (``result`` for a single batch) —
+    so a caller in the same process can check it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -69,7 +108,16 @@ def main():
                          "policy stamped into --plan-artifact; pass bf16 "
                          "explicitly to override a quantized artifact")
     ap.add_argument("--train-steps", type=int, default=30,
-                    help="brief training so weights are non-degenerate")
+                    help="brief training so weights are non-degenerate "
+                         "(0: random weights from --seed, no optimizer)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed for weights, training data and requests")
+    ap.add_argument("--num-layers", type=int, default=0,
+                    help="cut the config's depth to N layers (0: published "
+                         "depth); widths are never changed")
+    ap.add_argument("--no-autotune", action="store_true",
+                    help="keep the kernels' default tiles: do not read the "
+                         "autotune cache (kernels/autotune.py)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
@@ -202,9 +250,17 @@ def main():
                          "fences (device vs host-gap attribution)")
     ap.add_argument("--profile-dir", default="/tmp/repro-profile",
                     help="output dir for --profile-steps traces")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
+    if args.num_layers:
+        if not 0 < args.num_layers <= cfg.num_layers:
+            raise SystemExit(f"--num-layers must be in 1..{cfg.num_layers}")
+        if args.num_layers < cfg.num_layers:
+            print(f"depth cut: {args.num_layers} of {cfg.num_layers} layers "
+                  f"of {cfg.name}")
+            cfg = dataclasses.replace(cfg, num_layers=args.num_layers)
 
     mesh = None
     if args.mesh:
@@ -280,7 +336,7 @@ def main():
             args.num_requests, vocab_size=cfg.vocab_size,
             prompt_len=args.prompt_len, max_new_tokens=args.max_new,
             arrival_rate=args.arrival_rate, poisson=args.poisson,
-            priorities=priorities)
+            priorities=priorities, seed=args.seed)
         for r in requests:
             if args.queue_timeout_steps:
                 r.queue_timeout_steps = args.queue_timeout_steps
@@ -300,11 +356,29 @@ def main():
     if spec is not None:
         max_seq += spec.k   # verify-window headroom (engine asserts)
 
+    def build_engines(make_engine):
+        """The serving engine, or with --dp one engine per data-axis
+        replica (no full-mesh copy of the weights is built) behind a
+        router; returns (engine, replica router or None)."""
+        if not args.dp:
+            return make_engine(mesh), None
+        from repro.launch.mesh import split_data_replicas
+        from repro.serving.replica import ReplicaServe
+        subs = split_data_replicas(mesh)
+        if len(subs) < 2:
+            raise SystemExit(f"--dp found {len(subs)} replica(s) in "
+                             f"mesh {dict(mesh.shape)}; need a data "
+                             "axis of size >= 2")
+        if keep_submesh_programs_out():
+            print("compile cache off for the rest of the process: cached "
+                  "executables of a TPU submesh halt the chip")
+        engines = [make_engine(m) for m in subs]
+        return engines[0], ReplicaServe(engines)
+
     from repro.checkpoint import ckpt
     if args.plan_artifact and ckpt.is_artifact(args.plan_artifact):
         # cold boot: quantized weights straight from the compiled artifact —
         # no training/raw-weight load, no entropy analysis, no quantization
-        from repro.models.model import build
         model = build(cfg)
         t0 = time.perf_counter()
         # None = not specified -> the artifact's stamped kv policy governs;
@@ -315,19 +389,37 @@ def main():
         def make_engine(m):
             return ServeEngine.from_artifact(model, args.plan_artifact,
                                              max_seq=max_seq, mesh=m,
-                                             spec=spec, paged=paged, **kv_kw)
+                                             spec=spec, paged=paged,
+                                             autotune=not args.no_autotune,
+                                             **kv_kw)
 
-        engine = make_engine(mesh)
+        engine, replica = build_engines(make_engine)
         plan = engine.plan
         print(f"booted from artifact {args.plan_artifact} in "
               f"{time.perf_counter() - t0:.2f}s"
               + (" (weights landed sharded)" if mesh is not None else ""))
     else:
-        run = RunConfig(steps=args.train_steps, learning_rate=1e-3,
-                        warmup_steps=3, remat=False)
-        result = train(cfg, run, batch=args.batch, seq=args.prompt_len * 2)
-        model, params = result["model"], result["params"]
+        if args.train_steps > 0:
+            run = RunConfig(steps=args.train_steps, learning_rate=1e-3,
+                            warmup_steps=3, remat=False, seed=args.seed)
+            params = train(cfg, run, batch=args.batch,
+                           seq=args.prompt_len * 2)["params"]
+            model = build(cfg)
+        else:
+            model = build(cfg)
+            init_mesh = mesh
+            if args.dp:
+                # quantize over every device before the replicas take
+                # their copies: a replica-sharded raw model would not fit
+                init_mesh = jax.sharding.Mesh(
+                    mesh.devices.reshape((1,) * (mesh.devices.ndim - 1)
+                                         + (-1,)), mesh.axis_names)
+            params = init_params(model, args.seed, init_mesh)
+            print(f"initialized {cfg.name} from seed {args.seed} "
+                  f"(no training)")
+        print_device_memory("init")
         plan = plan_for_variant(model, params, args.variant, fast=args.fast)
+        print_device_memory("plan")
         kv_precision = args.kv_precision or "bf16"
         if kv_precision == "auto" and plan is None:
             raise SystemExit("--kv-precision auto derives per-layer cache "
@@ -336,16 +428,19 @@ def main():
         if plan is not None:
             compiled = model.compile_plan(params, plan,
                                           kv_precision=kv_precision)
+            params = None    # raw weights no longer needed: free them
+            print_device_memory("compile_plan")
 
             def make_engine(m):
                 e = ServeEngine(model, compiled.params, max_seq=max_seq,
                                 mesh=m,
                                 kv_precision=compiled.kv_plan or "bf16",
-                                spec=spec, paged=paged)
+                                spec=spec, paged=paged,
+                                autotune=not args.no_autotune)
                 e.plan = plan
                 return e
 
-            engine = make_engine(mesh)
+            engine, replica = build_engines(make_engine)
             if args.plan_artifact:
                 from repro.quant.compiler import save_artifact
                 if spec is not None and spec.draft_source == "model":
@@ -358,10 +453,12 @@ def main():
             def make_engine(m):
                 return ServeEngine(model, params, max_seq=max_seq, mesh=m,
                                    kv_precision=kv_precision, spec=spec,
-                                   paged=paged)
+                                   paged=paged,
+                                   autotune=not args.no_autotune)
 
-            engine = make_engine(mesh)
+            engine, replica = build_engines(make_engine)
 
+    print_device_memory("engine build")
     raw_bits = 32.0 if cfg.dtype == "float32" else 16.0
     raw_bytes = cfg.param_count() * raw_bits / 8.0
     print(f"weights: {engine.weight_bytes()/2**20:.1f} MiB effective "
@@ -369,7 +466,7 @@ def main():
     if mesh is not None:
         print(f"per-device weight bytes: "
               f"{engine.weight_bytes_per_device()/2**20:.1f} MiB "
-              f"on {mesh.size} devices")
+              f"on {engine.mesh.size} devices")
     if plan:
         print(f"plan: {plan.counts()}")
     if engine.kv_plan is not None:
@@ -395,16 +492,6 @@ def main():
                         prefill_chunk=args.prefill_chunk or None, slo=slo,
                         degrade=degrade)
         rstats = None
-        replica = None
-        if args.dp:
-            from repro.launch.mesh import split_data_replicas
-            from repro.serving.replica import ReplicaServe
-            subs = split_data_replicas(mesh)
-            if len(subs) < 2:
-                raise SystemExit(f"--dp found {len(subs)} replica(s) in "
-                                 f"mesh {dict(mesh.shape)}; need a data "
-                                 "axis of size >= 2")
-            replica = ReplicaServe([make_engine(m) for m in subs])
         chaos_ref = None
         if args.check_chaos_parity:
             # fault-free baseline FIRST, at nominal precision (no degrade):
@@ -504,7 +591,7 @@ def main():
                                  "lost)")
         if args.check_dp_parity:
             import numpy as np
-            ref_out, _ = engine.serve(requests, **serve_kw)
+            ref_out, _ = make_engine(mesh).serve(requests, **serve_kw)
             agree = (len(ref_out) == len(outputs)
                      and all(a.rid == b.rid
                              and np.array_equal(a.tokens, b.tokens)
@@ -544,9 +631,11 @@ def main():
                 raise SystemExit("speculative greedy output DIVERGED from "
                                  "the non-spec engine")
         print("sample:", outputs[0].generated.tolist())
-        return
+        return dict(model=model, engine=engine, replica=replica, plan=plan,
+                    requests=requests, outputs=outputs, stats=stats,
+                    max_seq=max_seq)
 
-    prompts = jax.random.randint(jax.random.PRNGKey(7),
+    prompts = jax.random.randint(jax.random.PRNGKey(args.seed + 7),
                                  (args.batch, args.prompt_len), 0,
                                  cfg.vocab_size, dtype=jnp.int32)
     out = engine.generate(prompts, args.max_new, chunk=args.chunk)
@@ -577,6 +666,8 @@ def main():
             raise SystemExit("paged greedy output DIVERGED from the dense "
                              "(contiguous) engine")
     print("sample:", out.tokens[0, -args.max_new:].tolist())
+    return dict(model=model, engine=engine, plan=plan, result=out,
+                max_seq=max_seq)
 
 
 if __name__ == "__main__":

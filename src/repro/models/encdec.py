@@ -20,9 +20,9 @@ import jax.numpy as jnp
 
 from repro.models import attention as A
 from repro.models import mlp as M
-from repro.models.common import (decode_positions, dtype_of, embed_init,
-                                 embed_lookup, dense_init, layer_norm,
-                                 lm_head, sinusoidal_positions)
+from repro.models.common import (LayerBlocks, decode_positions, dtype_of,
+                                 embed_init, embed_lookup, dense_init,
+                                 layer_norm, lm_head, sinusoidal_positions)
 from repro.sharding.ctx import constrain, unroll_flag, unshard_fsdp
 
 
@@ -252,11 +252,7 @@ def spec_commit(snap, committed: jax.Array) -> EncDecCache:
     return cache._replace(pos=cache.pos - s + committed)
 
 
-def block_params(params) -> list[Any]:
+def block_params(params) -> LayerBlocks:
     """[embed, enc_0..enc_{Le-1}, dec_0..dec_{Ld-1}] — two stacks, one plan."""
-    blocks = [params["embed"]]
-    for name in ("enc_layers", "dec_layers"):
-        layers = params[name]
-        n = jax.tree.leaves(layers)[0].shape[0]
-        blocks += [jax.tree.map(lambda x: x[i], layers) for i in range(n)]
-    return blocks
+    return LayerBlocks(params["embed"],
+                       [params["enc_layers"], params["dec_layers"]])

@@ -24,8 +24,8 @@ import jax.numpy as jnp
 from repro.models import attention as A
 from repro.models import mlp as M
 from repro.models import ssm as S
-from repro.models.common import (decode_positions, dtype_of, embed_init,
-                                 embed_lookup, lm_head, norm)
+from repro.models.common import (LayerBlocks, decode_positions, dtype_of,
+                                 embed_init, embed_lookup, lm_head, norm)
 from repro.sharding.ctx import constrain, unroll_flag, unshard_fsdp
 
 
@@ -274,10 +274,6 @@ def spec_commit(snap, committed: jax.Array) -> HybridCache:
                           pos=cache0.pos + committed)
 
 
-def block_params(params) -> list[Any]:
-    layers = params["layers"]
-    num_layers = jax.tree.leaves(layers)[0].shape[0]
-    blocks = [params["embed"]]
-    blocks += [jax.tree.map(lambda x: x[i], layers) for i in range(num_layers)]
-    blocks.append(params["shared"])
-    return blocks
+def block_params(params) -> LayerBlocks:
+    return LayerBlocks(params["embed"], [params["layers"]],
+                       [params["shared"]])

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Sequence
 from typing import Any, Callable, Optional
 
 import jax
@@ -143,7 +144,7 @@ class Model:
                              for d, s, a in zip(cache, one, axes)))
 
     # ---- EWQ --------------------------------------------------------------
-    def block_params(self, params) -> list:
+    def block_params(self, params) -> Sequence:
         return self.module.block_params(params)
 
     def compile_plan(self, params, plan, group: int = 128, **kw):

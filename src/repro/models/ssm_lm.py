@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import ssm as S
-from repro.models.common import (dtype_of, embed_init, embed_lookup, lm_head,
-                                 norm)
+from repro.models.common import (LayerBlocks, dtype_of, embed_init,
+                                 embed_lookup, lm_head, norm)
 from repro.sharding.ctx import constrain, unroll_flag, unshard_fsdp
 
 
@@ -140,8 +140,5 @@ def spec_commit(snap, committed: jax.Array) -> SSMLMCache:
                       pos=cache.pos + committed)
 
 
-def block_params(params) -> list[Any]:
-    layers = params["layers"]
-    num_layers = jax.tree.leaves(layers)[0].shape[0]
-    return [params["embed"]] + [jax.tree.map(lambda x: x[i], layers)
-                                for i in range(num_layers)]
+def block_params(params) -> LayerBlocks:
+    return LayerBlocks(params["embed"], [params["layers"]])

@@ -18,8 +18,9 @@ import jax.numpy as jnp
 from repro.models import attention as A
 from repro.models import mlp as M
 from repro.models import moe as MOE
-from repro.models.common import (decode_positions, dtype_of, embed_init,
-                                 embed_lookup, dense_init, lm_head, norm, qdot)
+from repro.models.common import (LayerBlocks, decode_positions, dtype_of,
+                                 embed_init, embed_lookup, dense_init, lm_head,
+                                 norm, qdot)
 from repro.sharding.ctx import constrain, unroll_flag, unshard_fsdp
 
 
@@ -309,11 +310,6 @@ def spec_commit(snap, committed: jax.Array) -> DecodeCache:
 # EWQ view
 # ---------------------------------------------------------------------------
 
-def block_params(params) -> list[Any]:
+def block_params(params) -> LayerBlocks:
     """[embedding block, layer_0, ..., layer_{L-1}] — paper exec_index order."""
-    layers = params["layers"]
-    num_layers = jax.tree.leaves(layers)[0].shape[0]
-    blocks = [params["embed"]]
-    for i in range(num_layers):
-        blocks.append(jax.tree.map(lambda x: x[i], layers))
-    return blocks
+    return LayerBlocks(params["embed"], [params["layers"]])

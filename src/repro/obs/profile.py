@@ -17,9 +17,9 @@ Two opt-in mechanisms, both armed by installing a ``ProfileHooks`` via
 * **Capture windows** (``steps=(A, B)``, CLI ``--profile-steps A:B``):
   ``jax.profiler.start_trace`` fires when the decode-step clock reaches
   A and stops at B (or at session teardown), writing an XPlane/Perfetto
-  trace under ``trace_dir``. Start/stop failures degrade to a warning —
-  profiler availability varies by backend and must never take serving
-  down.
+  trace under ``trace_dir``. A start/stop failure raises: profiling
+  was asked for, so a run that silently recorded nothing must not pass
+  for one that did.
 
 Disabled cost: the serve loop consults one module-level ``None`` check
 per site (``obs.profile()``), the same discipline as ``serving/chaos``.
@@ -79,11 +79,10 @@ class ProfileHooks:
         import jax
         try:
             jax.profiler.start_trace(self.trace_dir)
-            self._capturing = True
-        except Exception as e:   # profiler availability is backend-dependent
-            import warnings
-            warnings.warn(f"jax.profiler.start_trace failed: {e}")
-            self.steps = None    # don't retry every tick
+        except Exception:
+            self.steps = None    # one attempt per arm
+            raise
+        self._capturing = True
 
     def stop(self) -> None:
         """Close an open capture window (also called at session teardown
@@ -93,9 +92,5 @@ class ProfileHooks:
         import jax
         self._capturing = False
         self.steps = None        # one window per arm; never re-open
+        jax.profiler.stop_trace()
         self.windows += 1
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            import warnings
-            warnings.warn(f"jax.profiler.stop_trace failed: {e}")

@@ -16,6 +16,7 @@ Two layouts are supported:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Mapping, Sequence
 
 import jax
@@ -25,6 +26,13 @@ import numpy as np
 from repro.core.policy import QuantPlan
 from repro.quant.qtypes import QTensor
 from repro.quant.quantize import quantize
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _quantize(x, precision: str, group: int):
+    """One fused program per leaf shape: eager quantization of a
+    layer-stacked leaf would hold several f32 copies of it at once."""
+    return quantize(x, precision, group)
 
 
 def _quantizable(x: Any, group: int, min_ndim: int) -> bool:
@@ -43,7 +51,7 @@ def quantize_tree(tree: Any, precision: str, group: int = 128,
 
     def leaf(x):
         if _quantizable(x, group, min_ndim):
-            return quantize(x, precision, group)
+            return _quantize(x, precision, group)
         return x
 
     return jax.tree.map(leaf, tree)

@@ -317,6 +317,11 @@ class ServeEngine:
             with self._ctx():
                 return fn(*args, **kw)
 
+        def lower(*args, **kw):
+            with self._ctx():
+                return fn.lower(*args, **kw)
+
+        wrapped.lower = lower
         return wrapped
 
     def _shard_state(self, state: B.DecodeState) -> B.DecodeState:
@@ -355,45 +360,48 @@ class ServeEngine:
         return engine
 
     # -- prefill -------------------------------------------------------------
-    def _prefill_scan(self, prompts: jax.Array, cache):
+    # the prefill paths take ``params`` as an argument: a jitted function
+    # that closed over them would embed every weight as a program constant
+    def _prefill_scan(self, params, prompts: jax.Array, cache):
         """Universal prefill: scan decode steps over prompt tokens."""
 
         def body(cache, tok):
-            logits, cache = self.model.decode_step(self.params, cache,
+            logits, cache = self.model.decode_step(params, cache,
                                                    tok[:, None])
             return cache, logits[:, 0]
 
         cache, logits = jax.lax.scan(body, cache, prompts.T)
         return cache, logits[-1]  # logits after last prompt token
 
-    def _prefill_fused(self, prompts: jax.Array):
+    def _prefill_fused(self, params, prompts: jax.Array):
         """Transformer prefill: one fused forward emitting the KV cache."""
         from repro.models import transformer
         b, s = prompts.shape
         logits, _, cache = transformer.apply(
-            self.params, prompts, self.cfg, remat=False, return_cache=True,
+            params, prompts, self.cfg, remat=False, return_cache=True,
             last_only=True)
         pad = self.max_seq - s
         k = jnp.pad(cache.k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(cache.v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         return cache._replace(k=k, v=v), logits[:, 0]
 
-    def _prefill_impl(self, prompts: jax.Array):
+    def _prefill_impl(self, params, prompts: jax.Array):
         if self.cfg.family in ("dense", "moe"):
-            return self._prefill_fused(prompts)
-        return self._prefill_scan(prompts,
+            return self._prefill_fused(params, prompts)
+        return self._prefill_scan(params, prompts,
                                   self.model.init_cache(prompts.shape[0],
                                                         self.max_seq))
 
-    def _prefill_encdec(self, prompts: jax.Array, frames: jax.Array):
+    def _prefill_encdec(self, params, prompts: jax.Array,
+                        frames: jax.Array):
         """Enc-dec prefill: encode frames, precompute per-decoder-layer
         cross K/V, then scan decode steps over the prompt."""
         from repro.models import encdec
         cache = self.model.init_cache(prompts.shape[0], self.max_seq)
-        enc_out = encdec.encode(self.params, frames, self.cfg, remat=False)
-        ck, cv = encdec.precompute_cross_kv(self.params, enc_out, self.cfg)
+        enc_out = encdec.encode(params, frames, self.cfg, remat=False)
+        ck, cv = encdec.precompute_cross_kv(params, enc_out, self.cfg)
         cache = cache._replace(cross_k=ck, cross_v=cv)
-        return self._prefill_scan(prompts, cache)
+        return self._prefill_scan(params, prompts, cache)
 
     def _default_frames(self, batch: int) -> jax.Array:
         from repro.models.common import dtype_of
@@ -406,9 +414,9 @@ class ServeEngine:
             if frames is None:
                 frames = self._default_frames(prompts.shape[0])
             assert frames.shape[1] == self.cfg.encoder_seq
-            return self._prefill(prompts, frames)
+            return self._prefill(self.params, prompts, frames)
         assert frames is None, "frames only apply to enc-dec models"
-        return self._prefill(prompts)
+        return self._prefill(self.params, prompts)
 
     # -- paged KV pool + disaggregated API (docs/DESIGN.md §13) --------------
     def _pool_runs(self, raw) -> list:
@@ -1139,6 +1147,17 @@ class ServeEngine:
                             degrade=degrade).run()
 
     # -- diagnostics -----------------------------------------------------------
+    def compile_programs(self, prompt_len: int, num_slots: int,
+                         chunk: int = DEFAULT_CHUNK) -> dict:
+        """The engine's prefill (one ``prompt_len`` request) and plain
+        decode-chunk programs, compiled for these shapes — for inspecting
+        what the device runs (``as_text()``, ``memory_analysis()``)."""
+        prompts = jnp.zeros((1, prompt_len), jnp.int32)
+        state = self.init_decode_state(num_slots)
+        prefill = self._prefill.lower(self.params, prompts)
+        decode = self._make_chunk_fn(chunk).lower(self.params, state)
+        return {"prefill": prefill.compile(), "decode": decode.compile()}
+
     def kv_bytes_per_slot(self) -> float:
         """Physical attention-cache bytes one decode slot holds at
         ``max_seq`` (K/V payloads + per-group scales; enc-dec includes the

@@ -98,6 +98,14 @@ def data_shards() -> int:
     return rules["sizes"]["batch"] if rules else 1
 
 
+def sharded_trace() -> bool:
+    """True inside an ``activation_sharding`` context over more than one
+    device. The compiler cannot partition a Pallas (Mosaic) call, so the
+    kernel entry points take their jnp paths in such a trace."""
+    rules = _rules()
+    return rules is not None and rules["mesh"].size > 1
+
+
 def model_shards() -> int:
     rules = _rules()
     return rules["sizes"]["model"] if rules else 1

@@ -112,6 +112,30 @@ def test_block_params_counts():
         assert len(blocks) == expected, arch
 
 
+@pytest.mark.parametrize("family", ["dense", "ssm", "hybrid", "encdec"])
+def test_block_params_slice_stacks_on_read(family):
+    """Each block read from the lazy list equals the eager per-layer slice
+    of its stack, in exec order; slices and negative indices work too."""
+    arch = next(a for a in ARCHS
+                if get_config(a, smoke=True).family == family)
+    params = build(get_config(arch, smoke=True)).init(KEY)
+    blocks = build(get_config(arch, smoke=True)).block_params(params)
+    stacks = [params[k] for k in ("layers", "enc_layers", "dec_layers")
+              if k in params]
+    eager = [params["embed"]]
+    for stack in stacks:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        eager += [jax.tree.map(lambda x, i=i: x[i], stack) for i in range(n)]
+    if "shared" in params:
+        eager.append(params["shared"])
+    assert len(blocks) == len(eager)
+    for got, want in zip(blocks, eager):
+        assert jax.tree.all(jax.tree.map(
+            lambda a, b: bool(jnp.array_equal(a, b)), got, want))
+    assert jax.tree.structure(blocks[-1]) == jax.tree.structure(eager[-1])
+    assert len(blocks[1:3]) == 2
+
+
 def test_param_count_matches_init():
     import numpy as np
     for arch in ARCHS:

@@ -320,6 +320,25 @@ def test_profile_window_aligned_and_teardown_flush():
     assert prof.windows == 1
 
 
+@pytest.mark.parametrize("fails", ["start_trace", "stop_trace"])
+def test_profile_failure_raises(monkeypatch, fails):
+    """A capture that was asked for and could not start or stop fails the
+    run instead of passing for one that recorded a trace."""
+    import jax
+
+    def broken(*a, **k):
+        raise RuntimeError(f"{fails} unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, fails, broken)
+    prof = obs.ProfileHooks.parse("0:4")
+    with pytest.raises(RuntimeError, match=fails):
+        prof.tick(0)
+        prof.tick(4)
+    assert prof.steps is None and prof.windows == 0   # never re-armed
+
+
 def test_profile_parse_rejects_bad_specs():
     with pytest.raises(ValueError):
         obs.ProfileHooks.parse("3:1")

@@ -55,7 +55,7 @@ def _abstract_mesh(**axes):
     """Rule tests only need mesh.shape/axis_names — AbstractMesh lets us
     exercise 8-way layouts without 8 devices."""
     from jax.sharding import AbstractMesh
-    return AbstractMesh(tuple(axes.items()))
+    return AbstractMesh(tuple(axes.values()), tuple(axes))
 
 
 def test_param_specs_serving_tp_only():
@@ -229,7 +229,7 @@ def test_compressed_psum_matches_mean():
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.mesh import make_mesh
         from repro.optim.compress import compressed_psum_mean, init_error
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = make_mesh((8,), ("data",))
         g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 0.1
