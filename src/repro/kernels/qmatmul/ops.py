@@ -32,6 +32,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.quant.qtypes import QTensor
 from repro.quant.quantize import unpack_int4
 from repro.kernels.qmatmul.kernel import (DEFAULT_BK, DEFAULT_BM, DEFAULT_BN,
@@ -113,6 +114,9 @@ def _pallas_aligned(m: int, n: int, k: int, precision: str = "int8") -> bool:
     return m % 128 == 0 and n % 128 == 0 and lane % 512 == 0
 
 
+# The jnp paths run under the ``ewq/dequant`` scope: the dequantization
+# and the dot it feeds, which XLA may fuse into one instruction.
+@obs.scoped("ewq/dequant")
 def _dequant_fused(x2d: jax.Array, w: QTensor) -> jax.Array:
     """jnp fallback with the same math as the kernel: accumulate scaled
     per-group partial sums over a scan of the K/group blocks rather than
@@ -139,6 +143,7 @@ def _dequant_fused(x2d: jax.Array, w: QTensor) -> jax.Array:
     return y
 
 
+@obs.scoped("ewq/dequant")
 def _dequant_simple(x2d: jax.Array, w: QTensor) -> jax.Array:
     """Dequantize-then-dot fallback (lets XLA fuse convert into the dot)."""
     from repro.quant.quantize import dequantize

@@ -246,8 +246,9 @@ def main(argv=None) -> dict:
                          "next to it)")
     ap.add_argument("--profile-steps", default=None,
                     help="A:B — arm a jax.profiler capture window over "
-                         "decode steps [A, B) and per-chunk device-time "
-                         "fences (device vs host-gap attribution)")
+                         "decode steps [A, B); the trace carries the serve "
+                         "loop's serve/* spans and the model step's named "
+                         "scopes on the device clock")
     ap.add_argument("--profile-dir", default="/tmp/repro-profile",
                     help="output dir for --profile-steps traces")
     args = ap.parse_args(argv)

@@ -15,6 +15,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.decode_attn.ops import decode_attention
 from repro.models.common import qdot, rms_norm, rope
 from repro.quant import kvcache as KV
@@ -235,6 +236,7 @@ def _chunked_causal_attention(q, k, v):
     return out.reshape(b, s, h, d)
 
 
+@obs.scoped("attn")
 def attention(p, x, *, num_heads: int, num_kv_heads: int, head_dim: int,
               positions: Optional[jax.Array] = None,
               rope_theta: Optional[float] = None,
@@ -293,10 +295,11 @@ def attention(p, x, *, num_heads: int, num_kv_heads: int, head_dim: int,
         # ``count``; the cache itself is never written (zero draft-side
         # KV traffic — the whole point of the fused propose path).
         fk, fv, count = fresh_kv
-        fk = jax.lax.dynamic_update_slice(
-            fk, k.astype(fk.dtype), (0, count, 0, 0))
-        fv = jax.lax.dynamic_update_slice(
-            fv, v.astype(fv.dtype), (0, count, 0, 0))
+        with jax.named_scope("kv"):
+            fk = jax.lax.dynamic_update_slice(
+                fk, k.astype(fk.dtype), (0, count, 0, 0))
+            fv = jax.lax.dynamic_update_slice(
+                fv, v.astype(fv.dtype), (0, count, 0, 0))
         out = decode_attention(q, cache.k, cache.v,
                                valid_len=cache_pos + count + s,
                                fresh_kv=(fk, fv, cache_pos))
@@ -308,21 +311,28 @@ def attention(p, x, *, num_heads: int, num_kv_heads: int, head_dim: int,
         if KV.is_kv_page(cache.k):
             # Quantized KV cache: quantize-on-insert, then stream the int8
             # / int4 pages through the fused online-softmax decode kernel.
-            k_cache = KV.update_page(cache.k, k, cache_pos)
-            v_cache = KV.update_page(cache.v, v, cache_pos)
+            with jax.named_scope("kv"):
+                k_cache = KV.update_page(cache.k, k, cache_pos)
+                v_cache = KV.update_page(cache.v, v, cache_pos)
             out = decode_attention(q, k_cache, v_cache,
                                    valid_len=cache_pos + s)
         else:
-            if getattr(cache_pos, "ndim", 0) == 1:
-                write = jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
-                    c, n, (p, 0, 0)))
-                k_cache = write(cache.k, k.astype(cache.k.dtype), cache_pos)
-                v_cache = write(cache.v, v.astype(cache.v.dtype), cache_pos)
-            else:
-                k_cache = jax.lax.dynamic_update_slice(
-                    cache.k, k.astype(cache.k.dtype), (0, cache_pos, 0, 0))
-                v_cache = jax.lax.dynamic_update_slice(
-                    cache.v, v.astype(cache.v.dtype), (0, cache_pos, 0, 0))
+            with jax.named_scope("kv"):
+                if getattr(cache_pos, "ndim", 0) == 1:
+                    write = jax.vmap(
+                        lambda c, n, p: jax.lax.dynamic_update_slice(
+                            c, n, (p, 0, 0)))
+                    k_cache = write(cache.k, k.astype(cache.k.dtype),
+                                    cache_pos)
+                    v_cache = write(cache.v, v.astype(cache.v.dtype),
+                                    cache_pos)
+                else:
+                    k_cache = jax.lax.dynamic_update_slice(
+                        cache.k, k.astype(cache.k.dtype),
+                        (0, cache_pos, 0, 0))
+                    v_cache = jax.lax.dynamic_update_slice(
+                        cache.v, v.astype(cache.v.dtype),
+                        (0, cache_pos, 0, 0))
             bias = valid_bias if valid_bias is not None else \
                 decode_valid_bias(cache_pos, s, k_cache.shape[1])
             out = _full_attention(q, k_cache, v_cache, bias)

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.qmatmul.ops import qdot
 from repro.quant.qtypes import QTensor
 from repro.quant.quantize import dequantize
@@ -155,6 +156,7 @@ def sinusoidal_positions(seq: int, dim: int) -> jax.Array:
 # Embedding lookup (quant-aware)
 # --------------------------------------------------------------------------
 
+@obs.scoped("embed")
 def embed_lookup(table, ids: jax.Array, dtype) -> jax.Array:
     if isinstance(table, QTensor):
         rows = jnp.take(table.data, ids, axis=0)
@@ -171,6 +173,7 @@ def embed_lookup(table, ids: jax.Array, dtype) -> jax.Array:
     return jnp.take(table, ids, axis=0).astype(dtype)
 
 
+@obs.scoped("head")
 def lm_head(x: jax.Array, head_w, dtype=jnp.float32) -> jax.Array:
     """Final projection to (padded) vocab logits in f32."""
     return qdot(x, head_w, out_dtype=dtype)

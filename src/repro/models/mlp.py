@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.qmatmul.ops import fused_mlp
 from repro.models.common import dense_init, qdot
 
@@ -20,6 +21,7 @@ def gelu_mlp(p, x):
     return fused_mlp(x, None, p["w_up"], p["w_down"], act="gelu")
 
 
+@obs.scoped("mlp")
 def mlp(p, x, act: str):
     return swiglu(p, x) if act == "swiglu" else gelu_mlp(p, x)
 

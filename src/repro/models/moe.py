@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.common import dense_init, qdot
 from repro.quant.qtypes import QTensor
 from repro.quant.quantize import dequantize
@@ -48,6 +49,7 @@ def capacity_of(num_tokens: int, num_experts: int, top_k: int,
     return max(8, int(math.ceil(c / 8)) * 8)  # pad to VPU sublane
 
 
+@obs.scoped("mlp")
 def moe_block(p, x: jax.Array, *, num_experts: int, top_k: int,
               capacity_factor: float = 1.25):
     """x: (B, S, D) -> (y: (B, S, D), aux: dict with load-balancing loss)."""

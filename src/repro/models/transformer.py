@@ -110,6 +110,15 @@ def _layer(p, h, positions, cfg, cache_kv=None, cache_pos=None,
     return h, aux, new_kv
 
 
+def _head(params, h, embed_w, cfg):
+    """Final norm and logits, under the ``head`` scope (``lm_head`` opens
+    it for the logits)."""
+    with jax.named_scope("head"):
+        h = norm(h, params["final"].get("norm"), cfg)
+    head_w = unshard_fsdp(params["final"]).get("head", embed_w)
+    return constrain(lm_head(h, head_w), ("batch", None, "model"))
+
+
 # ---------------------------------------------------------------------------
 # forward (train / prefill)
 # ---------------------------------------------------------------------------
@@ -179,9 +188,7 @@ def apply(params, tokens: jax.Array, cfg, *, remat: bool = True,
 
     if last_only:
         h = h[:, -1:, :]
-    h = norm(h, params["final"].get("norm"), cfg)
-    head_w = unshard_fsdp(params["final"]).get("head", embed_w)
-    logits = constrain(lm_head(h, head_w), ("batch", None, "model"))
+    logits = _head(params, h, embed_w, cfg)
     aux = {k: jnp.sum(v) for k, v in (auxs or {}).items()}
     return (logits, aux, cache) if return_cache else (logits, aux)
 
@@ -220,18 +227,21 @@ def decode_step(params, cache: DecodeCache, tokens: jax.Array, cfg):
     from repro.quant.apply import segment_slices
     from repro.quant.kvcache import kv_rejoin, kv_segment
     ks, vs = [], []
-    for si, (part, lo, hi) in enumerate(segment_slices(params["layers"])):
-        h, (nk, nv) = jax.lax.scan(
-            body, h, (part, kv_segment(cache.k, si, lo, hi),
-                      kv_segment(cache.v, si, lo, hi)),
-            unroll=unroll_flag())
-        ks.append(nk)
-        vs.append(nv)
-    new_k = kv_rejoin(cache.k, ks)
-    new_v = kv_rejoin(cache.v, vs)
-    h = norm(h, params["final"].get("norm"), cfg)
-    head_w = unshard_fsdp(params["final"]).get("head", embed_w)
-    logits = constrain(lm_head(h, head_w), ("batch", None, "model"))
+    # ``kv``: the per-precision cache segments, the layer scan that carries
+    # them (its slicing and stacking of each layer's inputs and outputs)
+    # and the rejoin; the layer body's own scopes nest inside
+    with jax.named_scope("kv"):
+        for si, (part, lo, hi) in enumerate(
+                segment_slices(params["layers"])):
+            h, (nk, nv) = jax.lax.scan(
+                body, h, (part, kv_segment(cache.k, si, lo, hi),
+                          kv_segment(cache.v, si, lo, hi)),
+                unroll=unroll_flag())
+            ks.append(nk)
+            vs.append(nv)
+        new_k = kv_rejoin(cache.k, ks)
+        new_v = kv_rejoin(cache.v, vs)
+    logits = _head(params, h, embed_w, cfg)
     return logits, DecodeCache(k=new_k, v=new_v, pos=cache.pos + s)
 
 
@@ -269,19 +279,18 @@ def draft_propose_step(params, cache: DecodeCache, fresh_k, fresh_v,
     from repro.quant.apply import segment_slices
     from repro.quant.kvcache import kv_take_layers
     fks, fvs = [], []
-    for part, lo, hi in segment_slices(params["layers"]):
-        h, (nfk, nfv) = jax.lax.scan(
-            body, h, (part, kv_take_layers(cache.k, lo, hi),
-                      kv_take_layers(cache.v, lo, hi),
-                      fresh_k[lo:hi], fresh_v[lo:hi]),
-            unroll=unroll_flag())
-        fks.append(nfk)
-        fvs.append(nfv)
-    fresh_k = jnp.concatenate(fks, axis=0) if len(fks) > 1 else fks[0]
-    fresh_v = jnp.concatenate(fvs, axis=0) if len(fvs) > 1 else fvs[0]
-    h = norm(h, params["final"].get("norm"), cfg)
-    head_w = unshard_fsdp(params["final"]).get("head", embed_w)
-    logits = constrain(lm_head(h, head_w), ("batch", None, "model"))
+    with jax.named_scope("kv"):
+        for part, lo, hi in segment_slices(params["layers"]):
+            h, (nfk, nfv) = jax.lax.scan(
+                body, h, (part, kv_take_layers(cache.k, lo, hi),
+                          kv_take_layers(cache.v, lo, hi),
+                          fresh_k[lo:hi], fresh_v[lo:hi]),
+                unroll=unroll_flag())
+            fks.append(nfk)
+            fvs.append(nfv)
+        fresh_k = jnp.concatenate(fks, axis=0) if len(fks) > 1 else fks[0]
+        fresh_v = jnp.concatenate(fvs, axis=0) if len(fvs) > 1 else fvs[0]
+    logits = _head(params, h, embed_w, cfg)
     return logits, fresh_k, fresh_v
 
 

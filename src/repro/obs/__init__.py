@@ -31,8 +31,12 @@ or scoped, for tests::
 
 from __future__ import annotations
 
+import functools
 from contextlib import contextmanager
 from typing import Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.profile import ProfileHooks
@@ -43,7 +47,8 @@ __all__ = [
     "Tracer", "MetricsRegistry", "ProfileHooks",
     "ENGINE_TRACK", "DECODE_TRACK", "REQ_TRACK_BASE",
     "install", "capture", "tracer", "metrics", "profile", "enabled",
-    "request_phase", "request_done", "instant", "count", "observe",
+    "span", "scoped", "request_phase", "request_done", "instant", "count",
+    "observe",
 ]
 
 _KEEP = object()
@@ -98,8 +103,42 @@ def capture(tracer: Optional[Tracer] = None,
         install(*prev)
 
 
+def scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, a
+    fresh scope per call (``jax.named_scope`` used as a decorator shares
+    one context object among all calls). The name lands in the
+    ``op_name`` metadata of every instruction the call stages out, and so
+    on the device trace's ops; it changes no instruction."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return inner
+    return wrap
+
+
 # ---------------------------------------------------------------------------
 # Free no-op emitters: production call sites stay one None check when off.
+
+@contextmanager
+def span(name: str, pid: int = 0, args=None):
+    """A host span of the serve loop, on two clocks at once: always a
+    ``jax.profiler.TraceAnnotation`` (a no-op check in C++ unless a
+    profiler session is recording, where it lands on the device trace's
+    clock), and a Chrome B/E pair on the ENGINE track when a ``Tracer``
+    is installed. Per tick or per request, never per token."""
+    tr = _TRACER
+    with TraceAnnotation(name):
+        if tr is None:
+            yield
+            return
+        tr.begin(name, pid, args=args)
+        try:
+            yield
+        finally:
+            tr.end(name, pid)
+
 
 def request_phase(pid: int, rid: int, phase: str, args=None) -> None:
     if _TRACER is not None:

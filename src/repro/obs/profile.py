@@ -1,25 +1,14 @@
-"""Profiler hooks: device-time fences and ``jax.profiler`` capture
-windows (docs/DESIGN.md §16).
+"""Profiler capture windows (docs/DESIGN.md §16).
 
-Two opt-in mechanisms, both armed by installing a ``ProfileHooks`` via
-``obs.install(profile=...)``:
-
-* **Device fences** (``device_fences=True``): the serve loop adds a
-  ``jax.block_until_ready`` fence right after launching each decode
-  chunk, splitting PR 8's dispatch→harvest ``decode-gap`` wall into
-  *device compute* (launch → arrays ready) and *host scheduling gap*
-  (ready → harvest read). The split lands in the ``decode/chunk`` trace
-  span args and in the ``serve_device_time_seconds`` /
-  ``serve_host_gap_seconds`` histograms. The fence serializes the host
-  against the device — it is a measurement mode, not a serving mode, so
-  it is never on by default.
-
-* **Capture windows** (``steps=(A, B)``, CLI ``--profile-steps A:B``):
-  ``jax.profiler.start_trace`` fires when the decode-step clock reaches
-  A and stops at B (or at session teardown), writing an XPlane/Perfetto
-  trace under ``trace_dir``. A start/stop failure raises: profiling
-  was asked for, so a run that silently recorded nothing must not pass
-  for one that did.
+Armed by installing a ``ProfileHooks`` via ``obs.install(profile=...)``
+(CLI ``--profile-steps A:B``): ``jax.profiler.start_trace`` fires when
+the decode-step clock reaches A and stops at B (or at session teardown),
+writing an XPlane/Perfetto trace under ``trace_dir``. The trace carries
+the serve loop's ``serve/*`` host spans (``obs.span``) and the model
+step's named scopes on the device's clock, so the device/host split of
+each tick is read from the trace, with no fence in the serve loop. A
+start/stop failure raises: profiling was asked for, so a run that
+silently recorded nothing must not pass for one that did.
 
 Disabled cost: the serve loop consults one module-level ``None`` check
 per site (``obs.profile()``), the same discipline as ``serving/chaos``.
@@ -32,8 +21,7 @@ from typing import Optional
 
 class ProfileHooks:
     def __init__(self, steps: Optional[tuple] = None,
-                 trace_dir: str = "/tmp/repro-profile",
-                 device_fences: bool = True):
+                 trace_dir: str = "/tmp/repro-profile"):
         if steps is not None:
             a, b = steps
             if not (0 <= a < b):
@@ -41,20 +29,18 @@ class ProfileHooks:
                                  f"got {a}:{b}")
         self.steps = steps
         self.trace_dir = trace_dir
-        self.device_fences = device_fences
         self._capturing = False
         self.windows = 0              # capture windows actually recorded
 
     @classmethod
-    def parse(cls, spec: str, trace_dir: str = "/tmp/repro-profile",
-              device_fences: bool = True) -> "ProfileHooks":
+    def parse(cls, spec: str, trace_dir: str = "/tmp/repro-profile"
+              ) -> "ProfileHooks":
         """``"A:B"`` -> a capture window over decode steps [A, B)."""
         try:
             a, b = (int(x) for x in spec.split(":"))
         except ValueError:
             raise ValueError(f"--profile-steps wants A:B, got {spec!r}")
-        return cls(steps=(a, b), trace_dir=trace_dir,
-                   device_fences=device_fences)
+        return cls(steps=(a, b), trace_dir=trace_dir)
 
     # -- capture window -------------------------------------------------------
     def tick(self, clock: int) -> None:

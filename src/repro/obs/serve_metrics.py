@@ -90,10 +90,6 @@ SCHEMA = {
         ("histogram", "ready -> dequeue wait (separate from TTFT)"),
     "serve_decode_gap_seconds":
         ("histogram", "dispatch -> harvest wall per decode chunk"),
-    "serve_device_time_seconds":
-        ("histogram", "device compute per chunk (profiler fences)"),
-    "serve_host_gap_seconds":
-        ("histogram", "host scheduling gap per chunk (profiler fences)"),
     "serve_recovery_seconds":
         ("histogram", "replica failure -> survivors resumed"),
 }
@@ -162,7 +158,6 @@ def publish_session(reg, *, replica: int, outputs, occupancy: float,
                     watchdog_trips: int, degraded_steps: int,
                     transitions: int, tier_steps, tier_labels,
                     tuned: str, pool: Optional[dict] = None,
-                    device_times=(), host_gaps=(),
                     recovery=(), restarts: int = 0,
                     redriven: int = 0) -> None:
     """Write one serve run into ``reg``. ``outputs`` are RequestOutputs
@@ -211,12 +206,6 @@ def publish_session(reg, *, replica: int, outputs, occupancy: float,
     gap = _h(reg, "serve_decode_gap_seconds")
     for g_ in gaps:
         gap.observe(g_, replica=r)
-    dev = _h(reg, "serve_device_time_seconds")
-    for d in device_times:
-        dev.observe(d, replica=r)
-    hg = _h(reg, "serve_host_gap_seconds")
-    for h_ in host_gaps:
-        hg.observe(h_, replica=r)
 
     sl = dict(spec_labels or {})
     _c(reg, "serve_spec_rounds_total").inc(spec_m["rounds"], replica=r, **sl)

@@ -8,13 +8,15 @@ that Perfetto / chrome://tracing load directly.
 Track mapping:
 
 * ``pid`` = replica id. Process metadata names each ``replica<r>``.
-* ``tid 0`` = the replica's ENGINE track: ``tick/dispatch`` /
-  ``tick/harvest`` spans (one pair per ``ServeSession`` tick),
-  ``engine/apply_kv_plan`` repack spans, ``replica/failover`` spans and
-  ``degrade/transition`` / chaos instants.
+* ``tid 0`` = the replica's ENGINE track: the serve loop's ``serve/*``
+  spans (``obs.span``: ``serve/dispatch`` / ``serve/harvest`` per
+  ``ServeSession`` tick, nested policy, admission, launch, read-back and
+  completion spans), ``engine/apply_kv_plan`` repack spans,
+  ``replica/failover`` spans and ``degrade/transition`` / chaos
+  instants.
 * ``tid 1`` = the DECODE track: one ``decode/chunk`` X-span per launched
-  chunk (dispatch -> harvest wall; args carry the tier, the autotune
-  stamp and — with profiler fences armed — the device/host split).
+  chunk (dispatch -> harvest wall; args carry the tier and the autotune
+  stamp).
 * ``tid REQ_TRACK_BASE + rid`` = one track per REQUEST: its
   ``request/queued`` → ``request/prefill`` → ``request/decode`` phases
   are strictly sequential, so they form balanced B/E pairs; phase

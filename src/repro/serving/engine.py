@@ -381,8 +381,9 @@ class ServeEngine:
             params, prompts, self.cfg, remat=False, return_cache=True,
             last_only=True)
         pad = self.max_seq - s
-        k = jnp.pad(cache.k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(cache.v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+        with jax.named_scope("kv"):
+            k = jnp.pad(cache.k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+            v = jnp.pad(cache.v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         return cache._replace(k=k, v=v), logits[:, 0]
 
     def _prefill_impl(self, params, prompts: jax.Array):
@@ -581,7 +582,7 @@ class ServeEngine:
             model, max_seq = self.model, self.max_seq
             fields = self._paged_fields
 
-            def run(params, pools, row, hit, suffix):
+            def seed_prefix(params, pools, row, hit, suffix):
                 from repro.quant import paged as PG
                 from repro.quant.kvcache import dequantize_kv
                 cache = model.init_cache(1, max_seq)
@@ -605,7 +606,7 @@ class ServeEngine:
                 cache, logits = jax.lax.scan(body, cache, suffix.T)
                 return cache, logits[-1]
 
-            self._seed_fns[suffix_len] = self._traced(jax.jit(run))
+            self._seed_fns[suffix_len] = self._traced(jax.jit(seed_prefix))
         return self._seed_fns[suffix_len]
 
     def _seed_prefill(self, prompt: np.ndarray, m: PrefixMatch, state):
@@ -661,17 +662,17 @@ class ServeEngine:
         if self._pchunk_fn is None:
             model = self.model
             if self.cfg.family in ("dense", "moe", "encdec"):
-                def run(params, cache, toks):
+                def prefill_chunk(params, cache, toks):
                     logits, cache = model.decode_step(params, cache, toks)
                     return cache, logits[:, -1]
             else:
-                def run(params, cache, toks):
+                def prefill_chunk(params, cache, toks):
                     def body(c, tok):
                         logits, c = model.decode_step(params, c, tok[:, None])
                         return c, logits[:, 0]
                     cache, logits = jax.lax.scan(body, cache, toks.T)
                     return cache, logits[-1]
-            self._pchunk_fn = self._traced(jax.jit(run))
+            self._pchunk_fn = self._traced(jax.jit(prefill_chunk))
         return self._pchunk_fn
 
     def _pool_gather_fn(self):
@@ -683,7 +684,7 @@ class ServeEngine:
             model, max_seq = self.model, self.max_seq
             fields = self._paged_fields
 
-            def run(pools, row, hit):
+            def gather_prefix(pools, row, hit):
                 from repro.quant import paged as PG
                 from repro.quant.kvcache import dequantize_kv
                 cache = model.init_cache(1, max_seq)
@@ -700,7 +701,7 @@ class ServeEngine:
                 return cache._replace(pos=jnp.asarray(hit, jnp.int32),
                                       **reps)
 
-            self._gather_fn = self._traced(jax.jit(run))
+            self._gather_fn = self._traced(jax.jit(gather_prefix))
         return self._gather_fn
 
     def _encdec_seed(self, frames_b: jax.Array):
@@ -710,7 +711,7 @@ class ServeEngine:
         if self._encdec_seed_fn is None:
             model, max_seq = self.model, self.max_seq
 
-            def run(params, frames):
+            def encdec_seed(params, frames):
                 from repro.models import encdec
                 cache = model.init_cache(1, max_seq)
                 enc_out = encdec.encode(params, frames, self.cfg,
@@ -719,7 +720,7 @@ class ServeEngine:
                                                     self.cfg)
                 return cache._replace(cross_k=ck, cross_v=cv)
 
-            self._encdec_seed_fn = self._traced(jax.jit(run))
+            self._encdec_seed_fn = self._traced(jax.jit(encdec_seed))
         return self._encdec_seed_fn(self.params, frames_b)
 
     def begin_prefill(self, prompt, frames=None, state=None
@@ -835,22 +836,24 @@ class ServeEngine:
         model = self.model
 
         def step(params, st, _):
-            lp = jax.nn.log_softmax(
-                st.last_logits[:, :vocab].astype(jnp.float32), -1)
-            key, sub = jax.random.split(st.key)
-            dist = S.masked_dist(lp, st.temperature, st.top_k, st.top_p)
-            nxt = S.sample(sub, dist, st.temperature)
-            chosen_lp = jnp.take_along_axis(lp, nxt[:, None], 1)[:, 0]
-            advance = st.active & ~st.done
-            nxt = jnp.where(advance, nxt, pad_id).astype(jnp.int32)
-            at = jnp.arange(st.tokens.shape[1])[None, :] == st.lengths[:, None]
-            write = at & advance[:, None]
-            tokens = jnp.where(write, nxt[:, None], st.tokens)
-            logprobs = jnp.where(write, chosen_lp[:, None], st.logprobs)
-            lengths = st.lengths + advance.astype(jnp.int32)
-            done = st.done | (advance & (lengths >= st.max_len))
-            if eos_id is not None:
-                done = done | (advance & (nxt == eos_id))
+            with jax.named_scope("sample"):
+                lp = jax.nn.log_softmax(
+                    st.last_logits[:, :vocab].astype(jnp.float32), -1)
+                key, sub = jax.random.split(st.key)
+                dist = S.masked_dist(lp, st.temperature, st.top_k, st.top_p)
+                nxt = S.sample(sub, dist, st.temperature)
+                chosen_lp = jnp.take_along_axis(lp, nxt[:, None], 1)[:, 0]
+                advance = st.active & ~st.done
+                nxt = jnp.where(advance, nxt, pad_id).astype(jnp.int32)
+                at = (jnp.arange(st.tokens.shape[1])[None, :]
+                      == st.lengths[:, None])
+                write = at & advance[:, None]
+                tokens = jnp.where(write, nxt[:, None], st.tokens)
+                logprobs = jnp.where(write, chosen_lp[:, None], st.logprobs)
+                lengths = st.lengths + advance.astype(jnp.int32)
+                done = st.done | (advance & (lengths >= st.max_len))
+                if eos_id is not None:
+                    done = done | (advance & (nxt == eos_id))
             logits, cache = model.decode_step(params, st.cache, nxt[:, None])
             return st._replace(
                 cache=cache, last_logits=logits[:, 0].astype(jnp.float32),
@@ -930,11 +933,10 @@ class ServeEngine:
                 raise ValueError(
                     f"spec draft_layers needs the fused propose path; "
                     f"family {self.model.cfg.family!r} does not support it")
-            run = make_spec_round(self.model, self.spec.k, rounds,
-                                  self.eos_id, self.mesh,
-                                  fused_propose=fused,
-                                  draft_source=self.spec.draft_source)
-            self._chunk_fns[key] = self._traced(jax.jit(run))
+            spec_chunk = make_spec_round(
+                self.model, self.spec.k, rounds, self.eos_id, self.mesh,
+                fused_propose=fused, draft_source=self.spec.draft_source)
+            self._chunk_fns[key] = self._traced(jax.jit(spec_chunk))
         return self._chunk_fns[key]
 
     def _spec_budget_check(self, prompt_len: int, max_new: int):
@@ -1151,9 +1153,15 @@ class ServeEngine:
                          chunk: int = DEFAULT_CHUNK) -> dict:
         """The engine's prefill (one ``prompt_len`` request) and plain
         decode-chunk programs, compiled for these shapes — for inspecting
-        what the device runs (``as_text()``, ``memory_analysis()``)."""
-        prompts = jnp.zeros((1, prompt_len), jnp.int32)
-        state = self.init_decode_state(num_slots)
+        what the device runs (``as_text()``, ``memory_analysis()``). The
+        decode state is abstract: nothing is allocated, and a paged
+        engine's pool allocator is left as it was."""
+        prompts = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32)
+        pool, page_bytes = self.pool, self._page_bytes
+        try:
+            state = jax.eval_shape(lambda: self.init_decode_state(num_slots))
+        finally:
+            self.pool, self._page_bytes = pool, page_bytes
         prefill = self._prefill.lower(self.params, prompts)
         decode = self._make_chunk_fn(chunk).lower(self.params, state)
         return {"prefill": prefill.compile(), "decode": decode.compile()}

@@ -100,9 +100,6 @@ class ServeSession:
         _tr = obs.tracer()
         if _tr is not None:
             _tr.set_process_name(replica_id, f"replica{replica_id}")
-        self.device_times: list[float] = []   # fenced device s per chunk
-        self.host_gaps: list[float] = []      # gap - device per chunk
-        self._device_s: Optional[float] = None
         for r in requests:
             if self.spec:
                 engine._spec_budget_check(len(r.prompt), r.max_new_tokens)
@@ -154,15 +151,8 @@ class ServeSession:
         pf = obs.profile()
         if pf is not None:
             pf.tick(self.clock)
-        tr = obs.tracer()
-        if tr is None:
+        with obs.span("serve/dispatch", self.replica_id):
             self._dispatch()
-            return
-        tr.begin("tick/dispatch", self.replica_id)
-        try:
-            self._dispatch()
-        finally:
-            tr.end("tick/dispatch", self.replica_id)
 
     def _dispatch(self) -> None:
         eng, sched = self.engine, self.sched
@@ -172,12 +162,15 @@ class ServeSession:
         chaos.fire("replica.dispatch", tag=self.replica_id)
         chaos.fire("device.stall", tag=self.replica_id)
         now = time.perf_counter()
-        sched.poll(self.clock, now)
-        sched.expire(self.clock)
-        self._enforce_running_drops()
-        self._preempt_for_priority()
+        with obs.span("serve/policy", self.replica_id):
+            sched.poll(self.clock, now)
+            sched.expire(self.clock)
+            self._enforce_running_drops()
+            self._preempt_for_priority()
         stalled = self._admit(now)
-        if self._degrade_tick(stalled) and stalled:
+        with obs.span("serve/policy", self.replica_id):
+            degraded = self._degrade_tick(stalled)
+        if degraded and stalled:
             stalled = self._admit(now)   # lower tier freed pages: retry now
         self._advance_prefills()
         if sched.num_active == 0:
@@ -203,20 +196,13 @@ class ServeSession:
         use_spec = self.spec and not (
             self.tier > 0 and self.degrade is not None
             and self.degrade.shrink_spec)
-        if use_spec:
-            self.state, self._pending_spec = self.fn(
-                eng.params, self.draft_params, self.state)
-        else:
-            fn = self.fn if not self.spec else eng._chunk_fn(self.chunk)
-            self.state = fn(eng.params, self.state)
-        pf = obs.profile()
-        if pf is not None and pf.device_fences:
-            # fence right after the async launch: launch -> ready is the
-            # device-compute share of this chunk; harvest subtracts it
-            # from the dispatch->harvest gap to expose the host-side
-            # scheduling overhead (docs/DESIGN.md §16)
-            jax.block_until_ready(self.state.tokens)
-            self._device_s = time.perf_counter() - self._chunk_t0
+        with obs.span("serve/launch", self.replica_id):
+            if use_spec:
+                self.state, self._pending_spec = self.fn(
+                    eng.params, self.draft_params, self.state)
+            else:
+                fn = self.fn if not self.spec else eng._chunk_fn(self.chunk)
+                self.state = fn(eng.params, self.state)
         self.clock += self.chunk
         self.tier_steps[self.tier] += self.chunk
         if self.tier:
@@ -273,15 +259,8 @@ class ServeSession:
     # -- tick phase 2: the only blocking read ----------------------------------
     def harvest(self) -> None:
         """Read back the chunk ``dispatch`` launched and complete slots."""
-        tr = obs.tracer()
-        if tr is None:
+        with obs.span("serve/harvest", self.replica_id):
             self._harvest()
-            return
-        tr.begin("tick/harvest", self.replica_id)
-        try:
-            self._harvest()
-        finally:
-            tr.end("tick/harvest", self.replica_id)
 
     def _harvest(self) -> None:
         if not self._dispatched:
@@ -289,35 +268,27 @@ class ServeSession:
         chaos.fire("replica.harvest", tag=self.replica_id)
         self._dispatched = False
         eng, sched = self.engine, self.sched
-        if self._pending_spec is not None:
-            delta = {k_: int(v)
-                     for k_, v in self._pending_spec._asdict().items()}
-            for k_, v in delta.items():
-                self.spec_m[k_] += v
-            self._pending_spec = None
-            obs.instant("spec/round", self.replica_id, obs.DECODE_TRACK,
-                        args=delta)
-        done_np, len_np = jax.device_get((self.state.done,
-                                          self.state.lengths))
+        with obs.span("serve/readback", self.replica_id):
+            if self._pending_spec is not None:
+                delta = {k_: int(v)
+                         for k_, v in self._pending_spec._asdict().items()}
+                for k_, v in delta.items():
+                    self.spec_m[k_] += v
+                self._pending_spec = None
+                obs.instant("spec/round", self.replica_id, obs.DECODE_TRACK,
+                            args=delta)
+            done_np, len_np = jax.device_get((self.state.done,
+                                              self.state.lengths))
         now = time.perf_counter()
         if self._chunk_t0 is not None:
             gap = now - self._chunk_t0
             self.gaps.append(gap)
             tr = obs.tracer()
-            if tr is not None or self._device_s is not None:
-                args = {"steps": self.chunk, "tier": self.tier,
-                        "tuned": eng.tuned}
-                if self._device_s is not None:
-                    host = max(0.0, gap - self._device_s)
-                    self.device_times.append(self._device_s)
-                    self.host_gaps.append(host)
-                    args["device_ms"] = round(self._device_s * 1e3, 3)
-                    args["host_gap_ms"] = round(host * 1e3, 3)
-                    self._device_s = None
-                if tr is not None:
-                    tr.complete("decode/chunk", tr.now_us() - gap * 1e6,
-                                self.replica_id, obs.DECODE_TRACK,
-                                args=args)
+            if tr is not None:
+                tr.complete("decode/chunk", tr.now_us() - gap * 1e6,
+                            self.replica_id, obs.DECODE_TRACK,
+                            args={"steps": self.chunk, "tier": self.tier,
+                                  "tuned": eng.tuned})
             if self.watchdog_s is not None and gap > self.watchdog_s:
                 # dispatch->harvest deadline overrun: an in-process stall
                 # cannot be preempted, so it is surfaced (ServeStats
@@ -333,14 +304,16 @@ class ServeSession:
     def _complete_slot(self, slot: int, req: Request, n: int,
                        reason: Optional[str] = None) -> None:
         eng, sched = self.engine, self.sched
-        row = np.asarray(jax.device_get(self.state.tokens[slot, :n]))
-        lps = np.asarray(jax.device_get(
-            self.state.logprobs[slot, len(req.prompt):n]))
-        if reason is None:
-            reason = ("eos" if eng.eos_id is not None and n > 0
-                      and row[-1] == eng.eos_id else "length")
-        sched.complete(slot, row, lps, reason, self.clock)
-        self.state = eng.release(self.state, slot)
+        with obs.span("serve/complete", self.replica_id):
+            row = np.asarray(jax.device_get(self.state.tokens[slot, :n]))
+            lps = np.asarray(jax.device_get(
+                self.state.logprobs[slot, len(req.prompt):n]))
+            if reason is None:
+                reason = ("eos" if eng.eos_id is not None and n > 0
+                          and row[-1] == eng.eos_id else "length")
+            sched.complete(slot, row, lps, reason, self.clock)
+            with obs.span("serve/release", self.replica_id):
+                self.state = eng.release(self.state, slot)
         self.generated += n - len(req.prompt)
 
     # -- SLO enforcement -------------------------------------------------------
@@ -425,19 +398,23 @@ class ServeSession:
                 # the worst case — retry after a slot drains
                 sched.requeue(req)
                 return True
-            # the TTFT clock starts at dequeue (reserve) so prefill time
-            # (and the prefix cache skipping it) shows up in ttft_s
-            sched.reserve(slot, req, self.clock, wall=time.perf_counter())
-            if self.prefill_chunk is not None:
-                self.tasks[slot] = eng.begin_prefill(
-                    req.prompt, frames=req.frames, state=self.state)
-                continue
-            # monolithic: admission is baseline-identical even under spec
-            # (the spec loop recognizes pos == lengths as a fresh slot and
-            # takes the first candidate dist from these prefill logits)
-            pf = eng.prefill_request(req.prompt, frames=req.frames,
-                                     state=self.state)
-            self._insert(slot, req, pf)
+            with obs.span("serve/admit", self.replica_id):
+                # the TTFT clock starts at dequeue (reserve) so prefill
+                # time (and the prefix cache skipping it) shows up in ttft_s
+                sched.reserve(slot, req, self.clock,
+                              wall=time.perf_counter())
+                with obs.span("serve/prefill", self.replica_id):
+                    if self.prefill_chunk is not None:
+                        self.tasks[slot] = eng.begin_prefill(
+                            req.prompt, frames=req.frames, state=self.state)
+                        continue
+                    # monolithic: admission is baseline-identical even under
+                    # spec (the spec loop recognizes pos == lengths as a
+                    # fresh slot and takes the first candidate dist from
+                    # these prefill logits)
+                    pf = eng.prefill_request(req.prompt, frames=req.frames,
+                                             state=self.state)
+                self._insert(slot, req, pf)
         return False
 
     def _insert(self, slot: int, req: Request, pf) -> bool:
@@ -447,9 +424,10 @@ class ServeSession:
         temp = (req.temperature if req.temperature is not None
                 else self.temperature)
         try:
-            state = eng.insert(self.state, slot, pf, req.max_new_tokens,
-                               temperature=temp, top_k=req.top_k,
-                               top_p=req.top_p)
+            with obs.span("serve/insert", self.replica_id):
+                state = eng.insert(self.state, slot, pf, req.max_new_tokens,
+                                   temperature=temp, top_k=req.top_k,
+                                   top_p=req.top_p)
         except OutOfPages:
             # engine.insert unpinned the match and leaked nothing; put the
             # request back (its queue-delay clock resumes) and retry when
@@ -472,7 +450,8 @@ class ServeSession:
         preserves reservation order, so progress is FIFO."""
         for slot in list(self.tasks):
             task = self.tasks[slot]
-            self.engine.advance_prefill(task, self.prefill_chunk)
+            with obs.span("serve/prefill", self.replica_id):
+                self.engine.advance_prefill(task, self.prefill_chunk)
             self.prefill_chunks += 1
             if not task.done:
                 continue
@@ -555,8 +534,7 @@ class ServeSession:
             transitions=len(self.transitions),
             tier_steps=self.tier_steps,
             tier_labels=kv_tier_labels(self._ladder),
-            tuned=eng.tuned, pool=pool_kw,
-            device_times=self.device_times, host_gaps=self.host_gaps)
+            tuned=eng.tuned, pool=pool_kw)
         installed = obs.metrics()
         if installed is not None:
             installed.merge(local)

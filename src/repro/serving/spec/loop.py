@@ -308,7 +308,7 @@ def make_spec_round(model, k: int, rounds: int, eos_id, mesh=None,
     """Build the body the engine jits: ``rounds`` spec rounds in one scan
     (per-slot rollback stays inside the scan — no host sync mid-chunk)."""
 
-    def run(params, draft_params, state: B.DecodeState):
+    def spec_chunk(params, draft_params, state: B.DecodeState):
         def body(carry, _):
             st, m = carry
             st2, m2 = spec_round(model, params, draft_params, st, k, eos_id,
@@ -322,4 +322,4 @@ def make_spec_round(model, k: int, rounds: int, eos_id, mesh=None,
             state = B.constrain_state(state, mesh)
         return state, metrics
 
-    return run
+    return spec_chunk

@@ -163,12 +163,12 @@ def test_json_snapshot_is_stable_and_round_trips(tmp_path):
 
 def test_spans_balance_and_misnesting_asserts():
     tr = Tracer()
-    tr.begin("tick/dispatch", 0)
+    tr.begin("serve/dispatch", 0)
     tr.begin("inner", 0)
-    assert tr.open_spans() == [(0, ENGINE_TRACK, "tick/dispatch"),
+    assert tr.open_spans() == [(0, ENGINE_TRACK, "serve/dispatch"),
                                (0, ENGINE_TRACK, "inner")]
     tr.end("inner", 0)
-    tr.end("tick/dispatch", 0)
+    tr.end("serve/dispatch", 0)
     assert tr.open_spans() == []
     tr.begin("a", 0)
     with pytest.raises(AssertionError, match="misnesting"):
@@ -208,8 +208,8 @@ def test_trace_json_schema_golden():
     tr = Tracer()
     tr.set_process_name(0, "replica0")
     tr.set_process_name(0, "replica0")                   # idempotent
-    tr.begin("tick/dispatch", 0)
-    tr.end("tick/dispatch", 0)
+    tr.begin("serve/dispatch", 0)
+    tr.end("serve/dispatch", 0)
     t0 = tr.now_us()
     tr.complete("decode/chunk", t0, 0, DECODE_TRACK, args={"steps": 4})
     tr.instant("chaos/fire", 0, args={"site": "pool.oom"})
@@ -268,6 +268,65 @@ def test_capture_is_scoped():
         obs.instant("y", 0)
     assert obs.tracer() is None
     assert tr.counts()[("y", "i")] == 1
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records enters and
+    exits."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+        return Ann()
+
+
+def test_span_enters_a_profiler_annotation_with_nothing_installed(
+        monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(obs, "TraceAnnotation", ann)
+    assert obs.tracer() is None
+    with obs.span("serve/dispatch"):
+        with obs.span("serve/launch"):
+            pass
+    assert ann.log == [("enter", "serve/dispatch"), ("enter", "serve/launch"),
+                       ("exit", "serve/launch"), ("exit", "serve/dispatch")]
+
+
+def test_span_writes_balanced_pairs_with_a_tracer(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(obs, "TraceAnnotation", ann)
+    with obs.capture() as (tr, _):
+        with obs.span("serve/harvest", 3):
+            with obs.span("serve/complete", 3):
+                pass
+        with pytest.raises(RuntimeError):
+            with obs.span("serve/dispatch", 3):
+                raise RuntimeError("unwinds")
+    assert tr.open_spans() == []
+    names = [(e["name"], e["ph"]) for e in tr.events]
+    assert names == [("serve/harvest", "B"), ("serve/complete", "B"),
+                     ("serve/complete", "E"), ("serve/harvest", "E"),
+                     ("serve/dispatch", "B"), ("serve/dispatch", "E")]
+    assert all(e["pid"] == 3 and e["tid"] == ENGINE_TRACK
+               for e in tr.events)
+    assert [x for x, _ in ann.log].count("enter") == 3
+    assert ann.log[-1] == ("exit", "serve/dispatch")
+
+
+def test_span_annotation_is_real_outside_a_profiler_session():
+    """With no profiler session recording, the real annotation is a no-op
+    that still enters and exits cleanly."""
+    with obs.span("serve/policy"):
+        pass
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +457,6 @@ def test_publish_stats_fields_round_trip():
         pool=dict(pages_total=6, pages_peak=5, page_size=8, prefix_hits=2,
                   prefix_hit_tokens=12, prompt_tokens=24, cow_copies=1,
                   kv_bytes_peak=4096.0),
-        device_times=[0.01], host_gaps=[0.005],
         recovery=[0.2], restarts=1, redriven=4)
     f = stats_fields(reg)
     assert f["decode_steps"] == 20 and f["num_chunks"] == 5
@@ -488,7 +546,15 @@ def test_traced_stream_is_leak_free_and_stats_match(trained):
     assert counts[("request/decode", "B")] == len(reqs)
     assert counts[("request/finish", "i")] == len(reqs)
     assert counts[("decode/chunk", "X")] == stats.num_chunks
-    assert counts[("tick/dispatch", "B")] == counts[("tick/harvest", "B")]
+    assert counts[("serve/dispatch", "B")] == counts[("serve/harvest", "B")]
+    # the nested spans: one per admission, launch, read-back, completion
+    for name, n in (("serve/admit", len(reqs)), ("serve/prefill", len(reqs)),
+                    ("serve/insert", len(reqs)),
+                    ("serve/launch", stats.num_chunks),
+                    ("serve/readback", stats.num_chunks),
+                    ("serve/complete", len(reqs)),
+                    ("serve/release", len(reqs))):
+        assert counts[(name, "B")] == counts[(name, "E")] == n, name
     # tracing changes no tokens and no counted stats
     for a, b in zip(ref_out, out):
         np.testing.assert_array_equal(a.tokens, b.tokens)
