@@ -5,8 +5,10 @@ accepts block shapes, slices and VMEM footprints the chip's compiler
 refuses. Here each kernel is compiled — not run — for a v5e chip that is
 described, not attached, at the published widths of the serving models:
 minicpm-2b (d=2304, 36 heads of 64, MHA) for decode attention and yi-9b
-(d=4096, 4 KV heads of 128, d_ff=11008) for the weight kernels. A refusal
-here is one the chip would raise at the first serve.
+(d=4096, 4 KV heads of 128, d_ff=11008) for the weight kernels, at
+prefill M and, through the entry points' decode block rule, at the
+benchmark's decode slot counts. A refusal here is one the chip would
+raise at the first serve.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and the
@@ -21,8 +23,10 @@ import pytest
 
 from repro.kernels.decode_attn.kernel import decode_attn_pallas
 from repro.kernels.entropy.kernel import entropy_pallas
+from repro.kernels.qmatmul import ops
 from repro.kernels.qmatmul.kernel import (qkv_pallas, qmatmul_pallas,
                                           qmlp_pallas)
+from repro.quant.qtypes import QTensor
 from repro.kernels.quantize.kernel import quantize_int8_pallas
 
 # minicpm-2b decode: 8 slots, 576-row cache, int8 KV groups of 64
@@ -31,6 +35,8 @@ MINICPM = dict(hkv=36, rep=1, hd=64)
 YI = dict(hkv=4, rep=8, hd=128)
 # yi-9b weights at prefill M = one 256-token prompt
 M, D, FF, G = 256, 4096, 11008, 128
+# decode M: the docqa and decode-batch slot counts
+DECODE_SLOTS = (16, 64)
 
 
 @pytest.fixture(scope="module")
@@ -99,6 +105,30 @@ def test_qmlp_compiles(one_chip, precision):
         x, *_weight(one_chip, FF, D, precision),
         *_weight(one_chip, FF, D, precision),
         *_weight(one_chip, D, FF, precision))
+
+
+@pytest.mark.parametrize("precision", ["int8", "int4"])
+@pytest.mark.parametrize("m", DECODE_SLOTS)
+@pytest.mark.parametrize("entry", ["qkv", "qmlp", "qmatmul"])
+def test_decode_kernels_compile(one_chip, monkeypatch, entry, m, precision):
+    """The entry points at decode M with the decode rule's blocks (qkv:
+    4096 -> 4096/512/512; qmlp: 4096 <-> 11008; qmatmul: w_o 4096 x
+    4096)."""
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+
+    def q(n, k):
+        data, scale = _weight(one_chip, n, k, precision)
+        return QTensor(data=data, scale=scale, precision=precision,
+                       shape=(n, k), group=G)
+
+    x = _spec(one_chip, (m, 1, D), jnp.bfloat16)
+    kv = YI["hkv"] * YI["hd"]
+    if entry == "qkv":
+        _compile_has_kernel(ops.fused_qkv, x, q(D, D), q(kv, D), q(kv, D))
+    elif entry == "qmlp":
+        _compile_has_kernel(ops.fused_mlp, x, q(FF, D), q(FF, D), q(D, FF))
+    else:
+        _compile_has_kernel(ops.qdot, x, q(D, D))
 
 
 def _cache(sharding, rows, precision, dims, lead):
