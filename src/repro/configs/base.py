@@ -25,7 +25,19 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0            # expert hidden size (0 -> d_ff)
     dense_residual: bool = False  # arctic: dense FFN in parallel with MoE
-    capacity_factor: float = 1.25
+    # training drops tokens past an expert's capacity; None: dropless.
+    # Serving (prefill and decode) never drops (models/moe.py)
+    capacity_factor: Optional[float] = 1.25
+    first_k_dense: int = 0       # leading dense-MLP layers of an MoE stack
+    n_shared_experts: int = 0    # shared experts: one MLP of n * moe_d_ff
+    router_scoring: str = "softmax"  # "softmax" | "sigmoid"
+    router_bias: bool = False    # per-expert bias that chooses, never weights
+    routed_scaling: float = 1.0  # scale of the routed experts' weights
+    # --- MLA (DeepSeek-V3 latent attention, no q-LoRA; on when > 0) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (mamba2) ---
     ssm_state: int = 0
     ssm_headdim: int = 64
@@ -71,6 +83,20 @@ class ModelConfig:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one cached MLA row: the normalized latent and the
+        roped shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -84,6 +110,11 @@ class ModelConfig:
         d, f, v = self.d_model, self.d_ff, self.padded_vocab
         hd, nh, nkv = self.head_dim, self.num_heads, self.num_kv_heads
         attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d
+        if self.is_mla:
+            r = self.kv_lora_rank
+            attn = (d * nh * self.qk_head_dim + d * self.latent_dim + r
+                    + r * nh * (self.qk_nope_head_dim + self.v_head_dim)
+                    + nh * self.v_head_dim * d)
         if self.qk_norm:
             attn += 2 * hd
         mlp = 3 * d * f if self.mlp_act == "swiglu" else 2 * d * f
@@ -95,10 +126,14 @@ class ModelConfig:
             layers = self.num_layers * per_layer
         elif self.family == "moe":
             ef = self.expert_d_ff
-            moe = self.num_experts * 3 * d * ef + d * self.num_experts
+            moe = (self.num_experts * 3 * d * ef + d * self.num_experts
+                   + (self.num_experts if self.router_bias else 0)
+                   + 3 * d * ef * self.n_shared_experts)
             dense = 3 * d * f if self.dense_residual else 0
             per_layer = attn + moe + dense + norms
-            layers = self.num_layers * per_layer
+            k = self.first_k_dense
+            layers = ((self.num_layers - k) * per_layer
+                      + k * (attn + mlp + norms))
         elif self.family == "encdec":
             enc_layer = attn + 2 * d * f + 2 * d            # gelu mlp
             dec_layer = attn + attn + 2 * d * f + 3 * d     # self+cross+3 LN
@@ -130,7 +165,8 @@ class ModelConfig:
         if self.family != "moe":
             return self.param_count()
         d, ef = self.d_model, self.expert_d_ff
-        inactive = (self.num_experts - self.top_k) * 3 * d * ef * self.num_layers
+        inactive = ((self.num_experts - self.top_k) * 3 * d * ef
+                    * (self.num_layers - self.first_k_dense))
         return self.param_count() - inactive
 
 

@@ -17,6 +17,7 @@ _MODULES = {
     "olmo-1b": "repro.configs.olmo_1b",
     "zamba2-2.7b": "repro.configs.zamba2_27b",
     "mamba2-780m": "repro.configs.mamba2_780m",
+    "moonlight-16b": "repro.configs.moonlight_16b",
 }
 
 ARCHS = tuple(_MODULES)
@@ -38,7 +39,7 @@ def shape_runnable(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
 
 
 def cells(archs=ARCHS, shapes=tuple(SHAPES)):
-    """All 40 (arch, shape) cells with runnability annotations."""
+    """Every (arch, shape) cell with its runnability annotation."""
     out = []
     for a in archs:
         cfg = get_config(a)

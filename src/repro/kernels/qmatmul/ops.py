@@ -38,7 +38,8 @@ Which shapes the kernels take depends on M, the activation rows:
 
 Each dispatch of a quantized weight counts once, at trace time, into
 ``ewq_qmatmul_calls_total{path="pallas"|"dequant",
-regime="decode"|"prefill"}`` on the installed metrics registry.
+regime="decode"|"prefill"|"expert"}`` on the installed metrics registry
+("expert": a grouped matmul over a quantized expert stack, models/moe.py).
 """
 
 from __future__ import annotations
@@ -196,10 +197,14 @@ def _padded(x2d: jax.Array) -> jax.Array:
     return jnp.pad(x2d, ((0, pad), (0, 0))) if pad else x2d
 
 
-def _count(path: str, m: int) -> None:
+def count_dispatch(path: str, m: int, regime: str | None = None) -> None:
+    """Count one dispatch of a quantized weight over ``m`` activation rows
+    (``regime`` from ``m`` unless given: "expert" for a grouped expert
+    matmul)."""
     obs.count("ewq_qmatmul_calls_total", 1,
               "quantized matmul dispatches per traced program",
-              path=path, regime="decode" if m < DECODE_M else "prefill")
+              path=path,
+              regime=regime or ("decode" if m < DECODE_M else "prefill"))
 
 
 def _prefill_blocks(kernel: str, m: int, k: int, ns: tuple,
@@ -295,12 +300,12 @@ def qdot(x: jax.Array, w, out_dtype=None, backend: str | None = None
         m, n = x2d.shape[0], w.data.shape[0]
         blocks = _kernel_blocks(backend, "qmatmul", m, k, (n,), w)
         if blocks is not None:
-            _count("pallas", m)
+            count_dispatch("pallas", m)
             y = qmatmul_pallas(_padded(x2d), w.data,
                                w.scale, group=w.group,
                                precision=w.precision, **blocks)[:m]
         else:
-            _count("dequant", m)
+            count_dispatch("dequant", m)
             y = (_dequant_fused if backend == "grouped"
                  else _dequant_simple)(x2d, w)
         n_out = n
@@ -347,7 +352,7 @@ def fused_mlp(x: jax.Array, w_gate, w_up, w_down, act: str = "swiglu",
         ff, d = _out_dim(w_up), _out_dim(w_down)
         blocks = _kernel_blocks(backend, "qmlp", m, k, (ff, d), w)
         if blocks is not None:
-            _count("pallas", m)
+            count_dispatch("pallas", m)
             y = qmlp_pallas(
                 _padded(x2d),
                 None if w_gate is None else w_gate.data,
@@ -382,7 +387,7 @@ def fused_qkv(x: jax.Array, wq, wk, wv, backend: str | None = None):
         ns = tuple(_out_dim(w) for w in (wq, wk, wv))
         blocks = _kernel_blocks(backend, "qkv", m, k, ns, wq)
         if blocks is not None:
-            _count("pallas", m)
+            count_dispatch("pallas", m)
             yq, yk, yv = qkv_pallas(
                 _padded(x2d), wq.data, wq.scale, wk.data,
                 wk.scale, wv.data, wv.scale, group=wq.group,

@@ -164,7 +164,7 @@ def _full_attention(q, k, v, mask_bias):
     scores = scores + mask_bias  # (B,Hkv,rep,S,T) + broadcastable bias
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhrst,bthd->bshrd", probs.astype(v.dtype), v)
-    return out.reshape(b, s, h, d)
+    return out.reshape(b, s, h, v.shape[-1])
 
 
 def _chunked_causal_attention(q, k, v):
@@ -190,9 +190,10 @@ def _chunked_causal_attention(q, k, v):
     kv_chunk = kv_chunk_pref if t % kv_chunk_pref == 0 else t
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
 
+    dv = v.shape[-1]
     qc = q.reshape(b, nq, q_chunk, hkv, rep, d)
     kc = k.reshape(b, nk, kv_chunk, hkv, d)
-    vc = v.reshape(b, nk, kv_chunk, hkv, d)
+    vc = v.reshape(b, nk, kv_chunk, hkv, dv)
 
     q_pos = jnp.arange(q_chunk)
     k_pos = jnp.arange(kv_chunk)
@@ -219,7 +220,7 @@ def _chunked_causal_attention(q, k, v):
 
         m0 = jnp.full((b, hkv, rep, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hkv, rep, q_chunk), jnp.float32)
-        a0 = jnp.zeros((b, hkv, rep, q_chunk, d), v.dtype)
+        a0 = jnp.zeros((b, hkv, rep, q_chunk, dv), v.dtype)
         ks = jnp.arange(nk)
         (m, l, acc), _ = jax.lax.scan(
             body, (m0, l0, a0),
@@ -231,9 +232,22 @@ def _chunked_causal_attention(q, k, v):
     _, outs = jax.lax.scan(
         lambda c, args: (c, one_q_chunk(*args)), None,
         (jnp.arange(nq), jnp.moveaxis(qc, 1, 0)), unroll=unroll_flag())
-    # outs: (nq, b, hkv, rep, q_chunk, d) -> (b, s, h, d)
+    # outs: (nq, b, hkv, rep, q_chunk, dv) -> (b, s, h, dv)
     out = jnp.moveaxis(outs, 0, 1).transpose(0, 1, 4, 2, 3, 5)
-    return out.reshape(b, s, h, d)
+    return out.reshape(b, s, h, dv)
+
+
+def causal_attention(q, k, v):
+    """Causal self-attention of a whole sequence (train / prefill): the
+    scores materialized up to ``CHUNK_THRESHOLD`` tokens, the online-softmax
+    chunked form beyond. q/k: (B, S, H, d), v: (B, S, Hkv, dv), scaled by
+    1/sqrt(d)."""
+    s, t = q.shape[1], k.shape[1]
+    if s > CHUNK_THRESHOLD:
+        return _chunked_causal_attention(q, k, v)
+    causal_mask = jnp.tril(jnp.ones((s, t), bool))
+    bias = jnp.where(causal_mask, 0.0, NEG_INF)[None, None, None]
+    return _full_attention(q, k, v, bias)
 
 
 @obs.scoped("attn")
@@ -340,14 +354,7 @@ def attention(p, x, *, num_heads: int, num_kv_heads: int, head_dim: int,
     elif causal:
         new_cache = KVCache(k=k, v=v) if emit_kv else None
         q, k, v, h_orig = _flatten_gqa_for_sharding(q, k, v)
-        if s > CHUNK_THRESHOLD:
-            out = _chunked_causal_attention(q, k, v)
-        else:
-            t = k.shape[1]
-            causal_mask = jnp.tril(jnp.ones((s, t), bool))
-            bias = jnp.where(causal_mask, 0.0, NEG_INF)[None, None, None]
-            out = _full_attention(q, k, v, bias)
-        out = out[:, :, :h_orig, :]
+        out = causal_attention(q, k, v)[:, :, :h_orig, :]
     else:  # bidirectional (encoder)
         new_cache = KVCache(k=k, v=v) if emit_kv else None
         q, k, v, h_orig = _flatten_gqa_for_sharding(q, k, v)

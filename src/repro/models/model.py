@@ -93,11 +93,15 @@ class Model:
     def cache_batch_axes(self):
         """Cache NamedTuple of ints: batch axis per field in the slotted
         layout (``pos`` held as a (B,) per-slot vector)."""
+        if self.cfg.is_mla:
+            return transformer.LATENT_BATCH_AXES
         return self.module.CACHE_BATCH_AXES
 
     @property
     def kv_cache_fields(self) -> tuple:
         """Cache fields the engine may replace with quantized KVPages."""
+        if self.cfg.is_mla:
+            return transformer.LATENT_FIELDS
         return getattr(self.module, "KV_CACHE_FIELDS", ())
 
     def slotted_cache(self, num_slots: int, max_seq: int):

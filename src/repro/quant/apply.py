@@ -40,21 +40,28 @@ def _quantizable(x: Any, group: int, min_ndim: int) -> bool:
             and x.shape[-1] % group == 0 and x.shape[-1] % 2 == 0)
 
 
+# leaves that stay raw at every precision: the MoE router, whose logits
+# choose the experts in float32 (models/moe.py)
+RAW_LEAVES = ("router",)
+
+
 def quantize_tree(tree: Any, precision: str, group: int = 128,
                   min_ndim: int = 2) -> Any:
-    """Quantize every eligible leaf of a pytree; ineligible leaves pass
-    through. ``min_ndim=3`` for layer-stacked trees, where 1D per-layer
-    vectors (norm scales, biases, A_log) appear as 2D (L, D) leaves and must
-    stay raw (the paper quantizes Linear/Embedding weights only)."""
+    """Quantize every eligible leaf of a pytree; ineligible leaves (and
+    ``RAW_LEAVES``) pass through. ``min_ndim=3`` for layer-stacked trees,
+    where 1D per-layer vectors (norm scales, biases, A_log) appear as 2D
+    (L, D) leaves and must stay raw (the paper quantizes Linear/Embedding
+    weights only)."""
     if precision == "raw":
         return tree
 
-    def leaf(x):
-        if _quantizable(x, group, min_ndim):
+    def leaf(path, x):
+        name = getattr(path[-1], "key", None) if path else None
+        if name not in RAW_LEAVES and _quantizable(x, group, min_ndim):
             return _quantize(x, precision, group)
         return x
 
-    return jax.tree.map(leaf, tree)
+    return jax.tree_util.tree_map_with_path(leaf, tree)
 
 
 def apply_plan_blocks(blocks: list[Mapping[str, Any]], plan: QuantPlan,

@@ -7,7 +7,8 @@ layout, replacing the previous per-family branching in serving/quantized.py
 plans). Every family now yields quantized segmented stacks:
 
 * dense / moe / ssm — one layer stack, segmented into maximal runs of equal
-  precision (``SegmentedParams``);
+  precision (``SegmentedParams``); an MoE model's leading dense layers
+  (``first_k_dense``) are a second stack ahead of it;
 * hybrid — the Mamba2 layer stack is additionally cut at shared-attention
   unit boundaries when the plan is mixed, so each segment executes inside
   exactly one unit of the unit-scan (models/hybrid.py); the shared block is
@@ -73,6 +74,11 @@ def family_layout(cfg: ModelConfig) -> tuple[list[StackSpec], list[ExtraSpec]]:
     ``Model.block_params`` / the planner's exec_index convention.
     """
     n = cfg.num_layers
+    k = cfg.first_k_dense if cfg.family == "moe" else 0
+    if k:
+        # leading dense layers: a stack of their own ahead of the MoE stack
+        return ([StackSpec("dense_layers", 1, 1 + k),
+                 StackSpec("layers", 1 + k, 1 + n)], [ExtraSpec("embed", 0)])
     if cfg.family in ("dense", "moe", "ssm"):
         return [StackSpec("layers", 1, 1 + n)], [ExtraSpec("embed", 0)]
     if cfg.family == "hybrid":

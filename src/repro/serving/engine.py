@@ -213,6 +213,11 @@ class ServeEngine:
             raise ValueError(f"prefill_chunk must be >= 1 or None, got "
                              f"{prefill_chunk}")
         self.prefill_chunk = prefill_chunk
+        if self.cfg.is_mla and (paged or spec is not None):
+            raise ValueError(
+                f"{self.cfg.name}: multi-head latent attention serves from "
+                f"dense slots only; the paged pool, its prefix cache and "
+                f"speculative decoding do not hold its latent cache")
         # paged KV pool (docs/DESIGN.md §13): True -> defaults, or a
         # PagedConfig. Only plain K/V participates — enc-dec cross K/V is
         # per-request (frames-dependent, nothing to share) and stays in the
@@ -276,15 +281,16 @@ class ServeEngine:
                                kv_group or DEFAULT_KV_GROUP)
 
     def _kv_cuts(self) -> tuple:
-        """Page boundaries = the weight stack's segment boundaries, so each
+        """Page boundaries = the weight stacks' segment boundaries, so each
         cache page aligns 1:1 with a model scan segment."""
-        key = {"dense": "layers", "moe": "layers",
-               "encdec": "dec_layers"}.get(self.cfg.family)
-        if key is None:
+        if self.cfg.family in ("dense", "moe"):
+            from repro.models.transformer import layer_segments
+            return tuple(lo for _, lo, _ in layer_segments(self.params)[1:])
+        if self.cfg.family != "encdec":
             return ()
         from repro.quant.apply import segment_slices
         return tuple(lo for _, lo, _ in
-                     segment_slices(self.params[key])[1:])
+                     segment_slices(self.params["dec_layers"])[1:])
 
     def _wrap_cache(self, cache):
         """Raw (bf16) family cache -> quantized-page layout per the KV
@@ -380,11 +386,11 @@ class ServeEngine:
         logits, _, cache = transformer.apply(
             params, prompts, self.cfg, remat=False, return_cache=True,
             last_only=True)
-        pad = self.max_seq - s
+        pad = ((0, 0), (0, 0), (0, self.max_seq - s), (0, 0), (0, 0))
         with jax.named_scope("kv"):
-            k = jnp.pad(cache.k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(cache.v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        return cache._replace(k=k, v=v), logits[:, 0]
+            padded = {f: jnp.pad(getattr(cache, f), pad)
+                      for f in self.model.kv_cache_fields}
+        return cache._replace(**padded), logits[:, 0]
 
     def _prefill_impl(self, params, prompts: jax.Array):
         if self.cfg.family in ("dense", "moe"):

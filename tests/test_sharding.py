@@ -185,6 +185,52 @@ def test_small_mesh_train_lowering():
     assert "OK" in out
 
 
+def test_sharded_moe_dispatch_stays_group_local():
+    """A registry MoE config's train step on a 2x4 mesh: the sharded trace
+    takes the group-local capacity dispatch with expert-parallel
+    activations, not the grouped matmul over globally sorted rows (which
+    the compiler cannot partition by expert, so every device computes and
+    holds every expert's rows)."""
+    out = _run_subprocess("""
+        import jax
+        from repro.configs.base import ShapeConfig, RunConfig
+        from repro.configs.registry import get_config
+        from repro.launch.mesh import make_mesh
+        from repro.launch.steps import step_for_shape
+        from repro.launch.dryrun import input_shardings_for
+        from repro.sharding.specs import to_shardings
+        from repro.sharding.ctx import activation_sharding
+        from repro.models import moe
+        from repro.models.model import build
+
+        model = build(get_config("arctic-480b", smoke=True))
+        mesh = make_mesh((2, 4), ("data", "model"))
+        shape = ShapeConfig("t", 128, 8, "train")
+        fn, inputs = step_for_shape(model, shape, RunConfig(remat=False))
+        sh = to_shardings(input_shardings_for(model, shape, inputs, mesh),
+                          mesh)
+
+        def cost():
+            with mesh, activation_sharding(mesh):
+                c = jax.jit(fn, in_shardings=sh).lower(*inputs).compile()
+            ca = c.cost_analysis()
+            ca = ca[0] if isinstance(ca, list) else ca
+            return (ca["flops"], c.memory_analysis().temp_size_in_bytes,
+                    "ragged" in c.as_text())
+
+        local = cost()
+        moe._local_dispatch = moe._sorted_dispatch
+        jax.clear_caches()
+        flat = cost()
+        print("COST", local, flat)
+        assert not local[2]
+        assert 3 * local[0] < flat[0], (local, flat)
+        assert 2 * local[1] < flat[1], (local, flat)
+        print("OK")
+    """)
+    assert "OK" in out
+
+
 def test_small_mesh_execution_matches_single_device():
     """Sharded loss == single-device loss (8 virtual devices, real exec)."""
     out = _run_subprocess("""

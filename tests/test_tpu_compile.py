@@ -7,8 +7,10 @@ described, not attached, at the published widths of the serving models:
 minicpm-2b (d=2304, 36 heads of 64, MHA) for decode attention and yi-9b
 (d=4096, 4 KV heads of 128, d_ff=11008) for the weight kernels, at
 prefill M and, through the entry points' decode block rule, at the
-benchmark's decode slot counts. A refusal here is one the chip would
-raise at the first serve.
+benchmark's decode slot counts; moonlight-16b (d=2048, MLA latent rows of
+512 + 64, 64 experts of 1408) for absorbed latent decode attention, its
+quantized projections and the grouped expert matmul. A refusal here is one
+the chip would raise at the first serve.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and the
@@ -26,6 +28,7 @@ from repro.kernels.entropy.kernel import entropy_pallas
 from repro.kernels.qmatmul import ops
 from repro.kernels.qmatmul.kernel import (qkv_pallas, qmatmul_pallas,
                                           qmlp_pallas)
+from repro.models import moe
 from repro.quant.qtypes import QTensor
 from repro.kernels.quantize.kernel import quantize_int8_pallas
 
@@ -37,6 +40,15 @@ YI = dict(hkv=4, rep=8, hd=128)
 M, D, FF, G = 256, 4096, 11008, 128
 # decode M: the docqa and decode-batch slot counts
 DECODE_SLOTS = (16, 64)
+# moonlight-16b: 16 heads over one latent kv head of 512 + 64, 64 slots of
+# 5120 rows; projections q 16 x 192, kv_b 16 x 256 from 512, o 16 x 128;
+# shared experts 2 x 1408, the dense layer 11264; 64 experts of 1408
+ML_D, ML_HEADS, ML_LATENT, ML_R = 2048, 16, 576, 512
+ML_SLOTS, ML_SEQ, ML_PREFILL = 64, 5120, 1024
+ML_EXPERTS, ML_EF = 64, 1408
+ML_MATS = {"wq": (16 * 192, ML_D), "wo": (ML_D, 16 * 128),
+           "wkv_b": (16 * 256, ML_R)}
+ML_MLPS = {"shared": 2 * ML_EF, "dense": 11264}
 
 
 @pytest.fixture(scope="module")
@@ -192,3 +204,60 @@ def test_entropy_and_quantize_compile(one_chip):
                         _spec(one_chip, (2304, 5760), jnp.bfloat16))
     _compile_has_kernel(quantize_int8_pallas,
                         _spec(one_chip, (D, D), jnp.bfloat16))
+
+
+def test_mla_decode_attn_compiles(one_chip):
+    """Absorbed MLA decode: 16 f32 query heads over one 576-wide latent kv
+    head, V the same int8 rows (groups of 64), at 64 slots of 5120."""
+    dims = dict(hkv=1, rep=ML_HEADS, hd=ML_LATENT)
+    kd, ks = _cache(one_chip, ML_SEQ, "int8", dims, ML_SLOTS)
+    q = _spec(one_chip, (ML_SLOTS, 1, ML_HEADS, 1, ML_LATENT), jnp.float32)
+    valid = _spec(one_chip, (ML_SLOTS, 1), jnp.int32)
+    _compile_has_kernel(
+        lambda q, kd, ks, vl: decode_attn_pallas(
+            q, kd, ks, kd, ks, vl, precision="int8", group=KV_GROUP,
+            head_dim=ML_LATENT),
+        q, kd, ks, valid)
+
+
+# the quantized projections that take a kernel: w_q and w_o everywhere,
+# kv_b at prefill in int8 (int4 packs its 512 inputs under the 512-lane
+# block), the shared experts' MLP at decode (its 2816 misses the prefill
+# lane block), the dense layer's MLP everywhere
+ML_CASES = [(e, m, p) for p in ("int8", "int4")
+            for m in (ML_SLOTS, ML_PREFILL)
+            for e in ("wq", "wo", "wkv_b", "shared", "dense")
+            if not (e == "wkv_b" and (m == ML_SLOTS or p == "int4"))
+            and not (e == "shared" and m == ML_PREFILL)]
+
+
+@pytest.mark.parametrize("entry,m,precision", ML_CASES)
+def test_mla_projections_compile(one_chip, monkeypatch, entry, m, precision):
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+
+    def q(n, k):
+        data, scale = _weight(one_chip, n, k, precision)
+        return QTensor(data=data, scale=scale, precision=precision,
+                       shape=(n, k), group=G)
+
+    if entry in ML_MATS:
+        n, k = ML_MATS[entry]
+        x = _spec(one_chip, (m, 1, k), jnp.bfloat16)
+        _compile_has_kernel(ops.qdot, x, q(n, k))
+    else:
+        ff = ML_MLPS[entry]
+        x = _spec(one_chip, (m, 1, ML_D), jnp.bfloat16)
+        _compile_has_kernel(ops.fused_mlp, x, q(ff, ML_D), q(ff, ML_D),
+                            q(ML_D, ff))
+
+
+@pytest.mark.parametrize("rows", [ML_SLOTS * 6, ML_PREFILL * 6])
+@pytest.mark.parametrize("mat", ["gate", "down"])
+def test_expert_gmm_compiles(one_chip, rows, mat):
+    """The grouped expert matmul at decode (64 slots x top-6) and prefill
+    rows: gate/up (1408 from 2048) and down (2048 from 1408)."""
+    n, k = (ML_EF, ML_D) if mat == "gate" else (ML_D, ML_EF)
+    _compile_has_kernel(
+        moe.gmm, _spec(one_chip, (rows, k), jnp.bfloat16),
+        _spec(one_chip, (ML_EXPERTS, n, k), jnp.bfloat16),
+        _spec(one_chip, (ML_EXPERTS,), jnp.int32))
