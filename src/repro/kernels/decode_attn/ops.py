@@ -7,7 +7,8 @@ scores all draft positions in one streaming pass with per-query causal
 offset masking (docs/DESIGN.md §11). ``k``/``v`` may be:
 
 * ``quant.kvcache.KVPage``   (int8 / packed int4 / bf16 + per-group scales)
-* plain jax.Array            (raw bf16 cache, (B, S, Hkv, hd))
+* plain jax.Array            (raw bf16 cache, (B, S, Hkv, hd), or one kv
+                              head's flat (B, S, F) rows — MLA's latent)
 
 Backend selection mirrors ``qdot`` (kernels/qmatmul/ops.py):
 
@@ -94,12 +95,13 @@ def _use_pallas() -> bool:
 
 def _page_of(x):
     """Normalize a cache operand to a KVPage (raw arrays become bf16-style
-    pages with no scales). PagedKV pool views pass through — every backend
-    reads them through the slot page table."""
+    pages with no scales; a rank-3 (B, S, F) array is one kv head stored
+    flat). PagedKV pool views pass through — every backend reads them
+    through the slot page table."""
     if isinstance(x, (KVPage, PagedKV)):
         return x
     return KVPage(data=x, scale=None, precision="bf16",
-                  head_dim=x.shape[-1], group=x.shape[-1])
+                  head_dim=x.shape[-1], group=x.shape[-1], flat=x.ndim == 3)
 
 
 def _valid_vec(valid_len, b: int, s: int) -> jax.Array:
@@ -241,9 +243,9 @@ def _pallas(q, kp, vp, valid, kv_chunk: int, causal: bool,
     paged = isinstance(kp, PagedKV)
 
     def flat(page):
-        # dense: (B, S, ...) -> (B, S, F_store); paged pool: (N, P, ...) ->
-        # (N, P, F_store) — the kernel's scalar-prefetched page table maps
-        # grid steps to physical pages
+        # dense: (B, S, ...) -> (B, S, F_store), a no-op for a flat page;
+        # paged pool: (N, P, ...) -> (N, P, F_store) — the kernel's
+        # scalar-prefetched page table maps grid steps to physical pages
         lead = page.data.shape[:2]
         data = page.data.reshape(*lead, -1)
         if page.scale is None:  # bf16 page: dummy unit scales, never read
@@ -284,9 +286,10 @@ def decode_attention(q: jax.Array, k, v, *,
                      kv_chunk: Optional[int] = None,
                      fresh_kv=None) -> jax.Array:
     """(Multi-)query GQA attention of q (B, S, H, hd) against a cached
-    K/V (KVPage or raw (B, T, Hkv, hd)). ``valid_len`` (scalar or per-slot
-    (B,)) counts valid cache rows INCLUDING the S freshly-written query
-    rows; with ``causal=True`` query i additionally only sees rows
+    K/V (KVPage, raw (B, T, Hkv, hd), or one head's raw flat (B, T, hd)).
+    ``valid_len`` (scalar or per-slot (B,)) counts valid cache rows
+    INCLUDING the S freshly-written query rows; with ``causal=True``
+    query i additionally only sees rows
     ``< valid_len - S + 1 + i`` (S=1 reduces to the plain decode mask),
     with ``causal=False`` every query sees all valid rows (cross-attention
     over precomputed encoder K/V). ``backend`` overrides the process-wide

@@ -8,7 +8,9 @@ with r = kv_lora_rank. A token's ``wkv_a`` output splits into the latent
 c (r wide, RMS-normalized) and one key of ``rope`` width shared by every
 head, roped. The cache holds exactly that, one row per token and layer:
 [c | k_pe], r + rope = 576 values at Moonlight's widths, in place of a K and
-a V per head.
+a V per head. The rows carry no head axis, raw (B, S, r + rope) or a flat
+KVPage (quant/kvcache.py): a size-1 head axis is a tiled minor dimension
+that made XLA relayout the whole cache several times a layer and step.
 
 * Prefill runs the expanded form: ``wkv_b`` turns each c into per-head
   k_nope and v, and attention is causal attention over q = [q_nope | q_pe],
@@ -70,15 +72,15 @@ def _rope(x, positions, cfg):
 
 def _project(p, x, positions, cfg):
     """-> q_nope (B, S, H, nope), q_pe (B, S, H, rope) roped, and the
-    cache rows (B, S, 1, r + rope): normalized latent, roped k_pe."""
+    cache rows (B, S, r + rope): normalized latent, roped k_pe."""
     b, s, _ = x.shape
     nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     q = qdot(x, p["wq"]).reshape(b, s, cfg.num_heads, cfg.qk_head_dim)
     kv = qdot(x, p["wkv_a"])
     c = rms_norm(kv[..., :r], p["latent"]["norm"], LATENT_EPS)
-    k_pe = _rope(kv[..., None, r:], positions, cfg)
+    k_pe = _rope(kv[..., None, r:], positions, cfg)[:, :, 0]
     q_pe = _rope(q[..., nope:], positions, cfg)
-    return q[..., :nope], q_pe, jnp.concatenate([c[:, :, None], k_pe], -1)
+    return q[..., :nope], q_pe, jnp.concatenate([c, k_pe], -1)
 
 
 def _wkv_b(p, x, cfg):
@@ -93,22 +95,23 @@ def _wkv_b(p, x, cfg):
 
 
 def _write(field, rows, pos):
-    """Store ``rows`` (B, s, 1, r + rope) at ``pos`` (scalar or (B,)):
-    quantize-on-insert into a KVPage, or a plain write into a raw cache."""
+    """Store ``rows`` (B, s, r + rope) at ``pos`` (scalar or (B,)):
+    quantize-on-insert into a flat KVPage, or a plain write into a raw
+    cache."""
     if KV.is_kv_page(field):
         return KV.update_page(field, rows, pos)
     rows = rows.astype(field.dtype)
     if getattr(pos, "ndim", 0) == 1:
         return jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(
-            c, n, (i, 0, 0)))(field, rows, pos)
-    return jax.lax.dynamic_update_slice(field, rows, (0, pos, 0, 0))
+            c, n, (i, 0)))(field, rows, pos)
+    return jax.lax.dynamic_update_slice(field, rows, (0, pos, 0))
 
 
 @obs.scoped("attn")
 def attention(p, x, cfg, positions, cache=None, cache_pos=None):
     """x: (B, S, D). Without ``cache``: causal prefill over x, returning
-    (out, the S cache rows (B, S, 1, r + rope)). With ``cache`` (the
-    layer's raw (B, T, 1, r + rope) rows or its KVPage) and ``cache_pos``
+    (out, the S cache rows (B, S, r + rope)). With ``cache`` (the
+    layer's raw (B, T, r + rope) rows or its flat KVPage) and ``cache_pos``
     (scalar or (B,)): the S tokens are written at ``cache_pos`` and attend
     the cache (query i sees rows <= cache_pos + i); returns (out, the
     updated cache)."""
@@ -117,9 +120,9 @@ def attention(p, x, cfg, positions, cache=None, cache_pos=None):
     vd = cfg.v_head_dim
     q_nope, q_pe, rows = _project(p, x, positions, cfg)
     if cache is None:
-        kvb = qdot(rows[:, :, 0, :r], p["wkv_b"]).reshape(b, s, h, nope + vd)
-        k_pe = jnp.broadcast_to(rows[:, :, :, r:], (b, s, h, rows.shape[-1]
-                                                    - r))
+        kvb = qdot(rows[..., :r], p["wkv_b"]).reshape(b, s, h, nope + vd)
+        k_pe = jnp.broadcast_to(rows[:, :, None, r:],
+                                (b, s, h, cfg.qk_rope_head_dim))
         q = jnp.concatenate([q_nope, q_pe], -1)
         k = jnp.concatenate([kvb[..., :nope], k_pe], -1)
         out = causal_attention(q, k, kvb[..., nope:])

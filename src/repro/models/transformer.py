@@ -39,7 +39,7 @@ class DecodeCache(NamedTuple):
 
 
 class LatentCache(NamedTuple):
-    c: jax.Array    # (L, B, S_max, 1, r + rope) — raw, or KVPage(s)
+    c: jax.Array    # (L, B, S_max, r + rope) — raw, or flat KVPage(s)
     pos: jax.Array  # int32 next write position — scalar, or (B,) per-slot
 
 
@@ -229,7 +229,7 @@ def apply(params, tokens: jax.Array, cfg, *, remat: bool = True,
 def init_cache(cfg, batch: int, max_seq: int):
     dtype = dtype_of(cfg)
     if cfg.is_mla:
-        shape = (cfg.num_layers, batch, max_seq, 1, cfg.latent_dim)
+        shape = (cfg.num_layers, batch, max_seq, cfg.latent_dim)
         return LatentCache(c=jnp.zeros(shape, dtype), pos=jnp.int32(0))
     shape = (cfg.num_layers, batch, max_seq, cfg.num_kv_heads, cfg.head_dim)
     return DecodeCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),

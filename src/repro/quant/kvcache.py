@@ -15,9 +15,20 @@ A page covers a contiguous run of cache layers at ONE precision:
           (L?, B, S, F // 2)       int8   ("int4", two nibbles per byte,
                                            stored FLAT over F = Hkv * hd)
           (L?, B, S, Hkv, hd)      bf16   ("bf16", scale is None)
+          (L?, B, S, F)            int8 | bf16  (``flat``: one kv head)
   scale : (L?, B, S, F // group)   bf16   — F = Hkv * hd, groups along the
           FLATTENED head axis so small head dims still amortize one bf16
           scale over ``group`` elements (bytes/slot stays ~bits/8 per elem).
+
+A cache with ONE kv head — MLA's latent rows, F = r + rope — is stored
+``flat``: no head axis at all, its rows written, sliced and read as
+(B, S, F). A tiled size-1 axis in the payload made XLA relayout the whole
+cache between the layer scan's slice, the per-slot write and the
+kernel's (B, S, F) view, several times a layer and step. The choice
+is made from the shape the page is made from: a raw stacked field of
+rank 4, (L, B, S, F), is flat; (L, B, S, Hkv, hd) keeps its heads. It is
+kept as static page metadata because under the layer scan's slicing the
+rank alone cannot tell (B, S, Hkv, hd) from (L, B, S, F).
 
 int4 pages drop the (Hkv, hd//2) head split in storage: the packed payload
 keeps the flat F/2 axis as its minor dimension. The bytes are identical
@@ -103,34 +114,40 @@ class KVPage:
     """One contiguous run of cache layers at a single precision.
 
     Shapes are derived from ``data`` (not static metadata) so pages stay
-    valid under scan/vmap slicing of the leading layer axis.
+    valid under scan/vmap slicing of the leading layer axis; only
+    ``flat`` (one kv head stored without its axis) is static.
     """
     data: Any                 # see module docstring
     scale: Any                # bf16 per-group scales, or None for "bf16"
     precision: str            # static
     head_dim: int             # static logical hd (int4 stores hd//2 bytes)
     group: int                # static, divides Hkv*hd
+    flat: bool = False        # static: one kv head, rows (..., S, hd)
 
     def tree_flatten(self):
         return (self.data, self.scale), (self.precision, self.head_dim,
-                                         self.group)
+                                         self.group, self.flat)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         data, scale = children
-        precision, head_dim, group = aux
+        precision, head_dim, group, flat = aux
         return cls(data=data, scale=scale, precision=precision,
-                   head_dim=head_dim, group=group)
+                   head_dim=head_dim, group=group, flat=flat)
 
     @property
     def num_kv_heads(self) -> int:
+        if self.flat:
+            return 1
         if self.precision == "int4":    # flat (..., F // 2) payload
             return 2 * self.data.shape[-1] // self.head_dim
         return self.data.shape[-2]
 
     @property
     def seq_len(self) -> int:
-        return self.data.shape[-2 if self.precision == "int4" else -4]
+        # rows sit ahead of (Hkv, hd), or of the flat (F or F // 2) axis
+        rows_last = self.flat or self.precision == "int4"
+        return self.data.shape[-2 if rows_last else -3]
 
 
 @jax.tree_util.register_pytree_node_class
@@ -166,6 +183,9 @@ class PagedKV:
     head_dim: int             # static logical hd (int4 stores hd//2 bytes)
     group: int                # static, divides Hkv*hd
     page_size: int            # static tokens per physical page
+    # pools hold K/V with its head axes: a one-head latent cache is served
+    # from dense slots only (the engine refuses a pool for it)
+    flat = False
 
     def tree_flatten(self):
         return (self.data, self.scale, self.table), (
@@ -208,21 +228,19 @@ def is_kv_page(x: Any) -> bool:
 # quantize / dequantize (flat-head grouping)
 # ---------------------------------------------------------------------------
 
-def _flat_groups(x: jax.Array, group: int) -> jax.Array:
-    """(..., Hkv, hd) -> (..., F//group, group) over the flattened heads."""
-    *lead, hkv, hd = x.shape
-    f = hkv * hd
-    assert f % group == 0, f"Hkv*hd={f} not divisible by kv group {group}"
-    return x.reshape(*lead, f // group, group)
-
-
-def quantize_kv(x: jax.Array, precision: str, group: int
+def quantize_kv(x: jax.Array, precision: str, group: int,
+                flat: bool = False
                 ) -> tuple[jax.Array, Optional[jax.Array]]:
-    """x: (..., Hkv, hd) float -> (data, scale) in the page layout."""
-    *lead, hkv, hd = x.shape
+    """x: (..., Hkv, hd) float, or a flat page's (..., F) rows -> (data,
+    scale) in the page layout."""
+    heads = x.shape[-1:] if flat else x.shape[-2:]
+    lead = x.shape[:x.ndim - len(heads)]
     if precision == "bf16":
         return x.astype(jnp.bfloat16), None
-    g = _flat_groups(x.astype(jnp.float32), group)
+    f = int(np.prod(heads))
+    assert f % group == 0, f"Hkv*hd={f} not divisible by kv group {group}"
+    # groups over the flattened heads
+    g = x.astype(jnp.float32).reshape(*lead, f // group, group)
     absmax = jnp.max(jnp.abs(g), axis=-1, keepdims=True)
     qmax = 127.0 if precision == "int8" else 7.0
     scale = absmax / qmax
@@ -230,17 +248,18 @@ def quantize_kv(x: jax.Array, precision: str, group: int
     q = jnp.clip(q, -qmax, qmax).astype(jnp.int8)
     scale = scale[..., 0].astype(jnp.bfloat16)
     if precision == "int8":
-        return q.reshape(*lead, hkv, hd), scale
+        return q.reshape(*lead, *heads), scale
     if precision == "int4":
-        assert hd % 2 == 0, f"int4 KV packing needs an even head dim, {hd}"
+        assert heads[-1] % 2 == 0, \
+            f"int4 KV packing needs an even head dim, {heads[-1]}"
         # split-half packing over the flat F axis: byte j holds flat
         # elements j (low nibble) and j + F/2 (high nibble), so the unpack
         # on the decode hot path is a single concat — no interleave
         # shuffle. Stored FLAT (..., F//2): see the module docstring.
-        flat = q.reshape(*lead, hkv * hd)
-        half = hkv * hd // 2
-        packed = ((flat[..., :half] & 0x0F)
-                  | ((flat[..., half:] & 0x0F) << 4)).astype(jnp.int8)
+        flat_q = q.reshape(*lead, f)
+        half = f // 2
+        packed = ((flat_q[..., :half] & 0x0F)
+                  | ((flat_q[..., half:] & 0x0F) << 4)).astype(jnp.int8)
         return packed, scale
     raise ValueError(f"cannot quantize KV to {precision!r}")
 
@@ -256,15 +275,18 @@ def _unpack_kv_int4(data: jax.Array) -> jax.Array:
 
 
 def dequantize_kv(page: KVPage, dtype=jnp.float32) -> jax.Array:
-    """Page -> (..., Hkv, hd) in ``dtype`` (bf16 pages: a plain cast)."""
+    """Page -> (..., Hkv, hd) in ``dtype`` (bf16 pages: a plain cast); a
+    flat page gets its one head axis back on the output only."""
     if page.precision == "bf16":
-        return page.data.astype(dtype)
+        out = page.data.astype(dtype)
+        return out[..., None, :] if page.flat else out
     data = page.data
-    if page.precision == "int4":
-        # unpack over the stored flat F axis; every op here runs with the
-        # wide F/2 (then F) minor dimension — the head split is restored
-        # only on the output reshape below
-        data = _unpack_kv_int4(data)                      # (..., F) int8
+    if page.precision == "int4" or page.flat:
+        # work over the stored flat F axis (int4: unpacked from F/2 first);
+        # every op here runs with the wide F/2 (then F) minor dimension —
+        # the head split is restored only on the output reshape below
+        if page.precision == "int4":
+            data = _unpack_kv_int4(data)                  # (..., F) int8
         *lead, f = data.shape
         g = data.astype(jnp.float32).reshape(*lead, f // page.group,
                                              page.group)
@@ -278,11 +300,13 @@ def dequantize_kv(page: KVPage, dtype=jnp.float32) -> jax.Array:
     return out.reshape(*lead, hkv, hd).astype(dtype)
 
 
-def make_page(raw: jax.Array, precision: str, group: int) -> KVPage:
-    """Quantize a raw (..., S, Hkv, hd) cache buffer into one page."""
-    data, scale = quantize_kv(raw, precision, group)
+def make_page(raw: jax.Array, precision: str, group: int,
+              flat: bool = False) -> KVPage:
+    """Quantize a raw (..., S, Hkv, hd) cache buffer, or a one-head
+    cache's (..., S, F) rows with ``flat``, into one page."""
+    data, scale = quantize_kv(raw, precision, group, flat)
     return KVPage(data=data, scale=scale, precision=precision,
-                  head_dim=raw.shape[-1], group=group)
+                  head_dim=raw.shape[-1], group=group, flat=flat)
 
 
 # ---------------------------------------------------------------------------
@@ -290,13 +314,15 @@ def make_page(raw: jax.Array, precision: str, group: int) -> KVPage:
 # ---------------------------------------------------------------------------
 
 def update_page(page, new: jax.Array, pos: jax.Array):
-    """Decode-step write: quantize ``new`` (B, s, Hkv, hd) and store it at
-    sequence position ``pos`` (scalar, or (B,) per-slot vector). Paged
-    fields scatter through the slot's page table instead (quant/paged.py)."""
+    """Decode-step write: quantize ``new`` (B, s, Hkv, hd), or (B, s, F)
+    for a flat page, and store it at sequence position ``pos`` (scalar, or
+    (B,) per-slot vector). Paged fields scatter through the slot's page
+    table instead (quant/paged.py)."""
     if isinstance(page, PagedKV):
         from repro.quant import paged
         return paged.update_pages(page, new, pos)
-    data_n, scale_n = quantize_kv(new, page.precision, page.group)
+    data_n, scale_n = quantize_kv(new, page.precision, page.group,
+                                  page.flat)
     data_n = data_n.astype(page.data.dtype)
 
     def write(dst, src, p):
@@ -319,13 +345,15 @@ def _page_lengths(field) -> list[int]:
 
 def insert_slot(field, src: jax.Array, slot) -> Any:
     """Admit a prefilled request: quantize the raw batch=1 cache ``src``
-    ((L, 1, S, Hkv, hd)) into slot ``slot`` of a slotted page field (batch
-    axis 1). ``field`` is a KVPage or tuple of KVPages over layer runs."""
+    ((L, 1, S, Hkv, hd), or (L, 1, S, F) for flat pages) into slot
+    ``slot`` of a slotted page field (batch axis 1). ``field`` is a KVPage
+    or tuple of KVPages over layer runs."""
     pages = field if isinstance(field, tuple) else (field,)
     out, lo = [], 0
     for page in pages:
         hi = lo + page.data.shape[0]
-        data_n, scale_n = quantize_kv(src[lo:hi], page.precision, page.group)
+        data_n, scale_n = quantize_kv(src[lo:hi], page.precision, page.group,
+                                      page.flat)
 
         def write(dst, new):
             start = (0, slot) + (0,) * (dst.ndim - 2)
@@ -345,7 +373,8 @@ def insert_slot(field, src: jax.Array, slot) -> Any:
 
 def quantize_cache_field(raw: jax.Array, plan: KVPlan,
                          cuts: Sequence[int] = ()) -> Any:
-    """Raw stacked (L, B, S, Hkv, hd) cache buffer -> page container.
+    """Raw stacked (L, B, S, Hkv, hd) cache buffer, or a one-head cache's
+    (L, B, S, F) rows (stored flat), -> page container.
 
     Single-run plans yield a bare KVPage; mixed plans a tuple of pages cut
     at ``cuts`` so page i aligns with parameter segment i."""
@@ -353,7 +382,8 @@ def quantize_cache_field(raw: jax.Array, plan: KVPlan,
     assert runs and runs[-1][2] == raw.shape[0], \
         (f"KV plan covers {runs[-1][2] if runs else 0} layers; cache has "
          f"{raw.shape[0]}")
-    pages = tuple(make_page(raw[lo:hi], prec, plan.group)
+    flat = raw.ndim == 4
+    pages = tuple(make_page(raw[lo:hi], prec, plan.group, flat)
                   for prec, lo, hi in runs)
     return pages if len(pages) > 1 else pages[0]
 

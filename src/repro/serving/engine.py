@@ -386,10 +386,15 @@ class ServeEngine:
         logits, _, cache = transformer.apply(
             params, prompts, self.cfg, remat=False, return_cache=True,
             last_only=True)
-        pad = ((0, 0), (0, 0), (0, self.max_seq - s), (0, 0), (0, 0))
+        # (L, 1, s, ...) -> (L, 1, max_seq, ...): K/V (..., Hkv, hd), or
+        # MLA's flat latent rows (..., r + rope)
         with jax.named_scope("kv"):
-            padded = {f: jnp.pad(getattr(cache, f), pad)
-                      for f in self.model.kv_cache_fields}
+            padded = {}
+            for f in self.model.kv_cache_fields:
+                x = getattr(cache, f)
+                pad = [(0, 0)] * x.ndim
+                pad[2] = (0, self.max_seq - s)
+                padded[f] = jnp.pad(x, pad)
         return cache._replace(**padded), logits[:, 0]
 
     def _prefill_impl(self, params, prompts: jax.Array):

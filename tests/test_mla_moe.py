@@ -176,9 +176,8 @@ def test_decode_through_latent_cache_matches_reference(kv):
     ref = _reference(p).logits(toks)
     lg, _, cache = T.apply(p, jnp.asarray(toks[:prompt])[None], CFG,
                            remat=False, return_cache=True)
-    assert cache.c.shape == (CFG.num_layers, 1, prompt, 1, CFG.latent_dim)
-    c = jnp.pad(cache.c, ((0, 0), (0, 0), (0, max_seq - prompt), (0, 0),
-                          (0, 0)))
+    assert cache.c.shape == (CFG.num_layers, 1, prompt, CFG.latent_dim)
+    c = jnp.pad(cache.c, ((0, 0), (0, 0), (0, max_seq - prompt), (0, 0)))
     if kv == "int8":
         cuts = [lo for _, lo, _ in T.layer_segments(p)[1:]]
         c = quantize_cache_field(

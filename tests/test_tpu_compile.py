@@ -17,12 +17,17 @@ import: only one process at a time may load the TPU library, and the
 test workers all import this file.
 """
 
+import collections
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs.registry import get_config
+from repro.kernels.decode_attn import ops as attn_ops
 from repro.kernels.decode_attn.kernel import decode_attn_pallas
 from repro.kernels.entropy.kernel import entropy_pallas
 from repro.kernels.qmatmul import ops
@@ -31,6 +36,8 @@ from repro.kernels.qmatmul.kernel import (qkv_pallas, qmatmul_pallas,
 from repro.models import moe
 from repro.quant.qtypes import QTensor
 from repro.kernels.quantize.kernel import quantize_int8_pallas
+from repro.models import transformer
+from repro.quant import kvcache
 
 # minicpm-2b decode: 8 slots, 576-row cache, int8 KV groups of 64
 SLOTS, SEQ, PAGE, KV_GROUP = 8, 576, 64, 64
@@ -218,6 +225,75 @@ def test_mla_decode_attn_compiles(one_chip):
             q, kd, ks, kd, ks, vl, precision="int8", group=KV_GROUP,
             head_dim=ML_LATENT),
         q, kd, ks, valid)
+
+
+def _whole_layer_s8(text, elems):
+    """(opcode, dims, fused) of each instruction in compiled HLO ``text``
+    that yields an s8 array of ``elems`` elements; ``fused`` marks one
+    inside a fusion's computation."""
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", text))
+    comp, out = None, []
+    for line in text.splitlines():
+        if not line.startswith(" ") and line.endswith("{"):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+            comp = comp.lstrip("%")
+            continue
+        m = re.search(r"= s8\[([\d,]*)\]\{[^}]*\} ([\w\-]+)\(", line)
+        if m:
+            dims = tuple(int(d) for d in m.group(1).split(","))
+            if math.prod(dims) == elems:
+                out.append((m.group(2), dims, comp in fused))
+    return out
+
+
+def test_mla_latent_cache_keeps_its_layout(one_chip, monkeypatch):
+    """The int8 latent cache of moonlight-16b-5L's decode (5 layers, 64
+    slots of 5120 rows of 576) through two steps of a layer scan that
+    carries it as xs/ys, as ``transformer.decode_step`` does: per-slot
+    quantize-on-insert, then the decode kernel over the written page. The
+    whole layer's s8 array moves at most twice a layer (the scan's slice
+    and its stack update), never through a copy, and no array of it
+    carries a size-1 axis (the head axis a one-head cache drops)."""
+    monkeypatch.setattr(attn_ops, "_use_pallas", lambda: True)
+    cfg = get_config("moonlight-16b")
+    layers = 5
+    raw = jax.eval_shape(lambda: transformer.init_cache(
+        cfg, ML_SLOTS, ML_SEQ).c[:layers])
+    plan = kvcache.KVPlan(("int8",) * layers, group=KV_GROUP)
+    page = jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda c: kvcache.quantize_cache_field(c, plan), raw))
+    rows = _spec(one_chip, (layers, ML_SLOTS, 1) + raw.shape[3:],
+                 jnp.bfloat16)
+    q = _spec(one_chip, (ML_SLOTS, 1, ML_HEADS, ML_LATENT), jnp.float32)
+    pos = _spec(one_chip, (ML_SLOTS,), jnp.int32)
+
+    def decode(page, q, rows, pos):
+        def layer(acc, xs):
+            lp, r = xs
+            new = kvcache.update_page(lp, r, pos)
+            o = attn_ops.decode_attention(q, new, new, valid_len=pos + 1)
+            return acc + o.sum(), new
+
+        def step(carry, _):
+            page, acc = carry
+            acc, page = jax.lax.scan(layer, acc, (page, rows))
+            return (page, acc), None
+
+        return jax.lax.scan(step, (page, jnp.float32(0)), None, length=2)[0]
+
+    text = jax.jit(decode, donate_argnums=0).lower(
+        page, q, rows, pos).compile().as_text()
+    assert "tpu_custom_call" in text
+    found = _whole_layer_s8(text, ML_SLOTS * ML_SEQ * ML_LATENT)
+    assert found, "no whole-layer s8 array in the compiled decode"
+    for op, dims, _ in found:
+        minor = dims[next(i for i, d in enumerate(dims) if d != 1):]
+        assert 1 not in minor, (op, dims)
+    moves = collections.Counter(
+        op for op, _, fused in found if not fused
+        and op not in ("parameter", "get-tuple-element", "bitcast", "tuple"))
+    assert moves["copy"] == 0 and sum(moves.values()) <= 2, moves
 
 
 # the quantized projections that take a kernel: w_q and w_o everywhere,
