@@ -13,13 +13,12 @@ from __future__ import annotations
 import sys
 import traceback
 
-from benchmarks import (common, roofline_report, serve_throughput,
-                        table1_mixed, table3_classifiers, table6_ewq,
-                        table7_fastewq, table8_selection, table9_sizes,
-                        table13_stats, table14_summary, table_fig1_entropy)
+from benchmarks import (common, roofline_report, table1_mixed,
+                        table3_classifiers, table6_ewq, table7_fastewq,
+                        table8_selection, table9_sizes, table13_stats,
+                        table14_summary, table_fig1_entropy)
 
 TABLES = {
-    "serve": serve_throughput,
     "fig1": table_fig1_entropy,
     "table1": table1_mixed,
     "table3": table3_classifiers,

@@ -5,8 +5,7 @@
 
 Every phase goes through the serving launcher's own ``main``
 (``repro.launch.serve``) in this one process, with random weights made
-from ``--seed`` at the models' published widths and the kernels' default
-tiles (``--no-autotune``: no tuned-tile cache is read):
+from ``--seed`` at the models' published widths:
 
 * one chip —
   - minicpm-2b, all 40 layers, full EWQ ``8bit-mixed`` plan, int8 KV
@@ -87,8 +86,9 @@ def teacher_forced_check(out: dict, n_check: int = CHECK_REQUESTS) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels.qmatmul.ops import get_qdot_backend, set_qdot_backend
     from repro.models.model import build
+    from repro.quant.qtypes import QTensor
+    from repro.quant.quantize import dequantize
 
     model, engine = out["model"], out["engine"]
     cfg = model.cfg
@@ -105,14 +105,14 @@ def teacher_forced_check(out: dict, n_check: int = CHECK_REQUESTS) -> dict:
             logits[:, :-1, :cfg.vocab_size].astype(jnp.float32), -1)
         return jnp.take_along_axis(lp, tokens[:, 1:, None], -1)[..., 0]
 
-    prev = get_qdot_backend()
-    set_qdot_backend("simple")
-    try:
-        with jax.default_matmul_precision("highest"):
-            got = np.asarray(jax.jit(chosen_logprobs)(engine.params,
-                                                      jnp.asarray(toks)))
-    finally:
-        set_qdot_backend(prev)
+    # the served weights dequantized as the jnp path dequantizes them, so
+    # that no kernel runs in the reference
+    params = jax.tree.map(
+        lambda w: dequantize(w, jnp.bfloat16) if isinstance(w, QTensor)
+        else w, engine.params, is_leaf=lambda w: isinstance(w, QTensor))
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(chosen_logprobs)(params, jnp.asarray(toks)))
+    del params
     diffs = np.concatenate([
         np.abs(o.logprobs - got[i, o.prompt_len - 1:len(o.tokens) - 1])
         for i, o in enumerate(sel)])
@@ -224,7 +224,7 @@ def serve_phase(name: str, argv: list, counter, *, decode_kernels=(),
 
 
 def one_chip(seed: int, counter) -> None:
-    base = ["--train-steps", "0", "--seed", str(seed), "--no-autotune",
+    base = ["--train-steps", "0", "--seed", str(seed),
             "--kv-precision", "int8"]
     minicpm = ["--arch", "minicpm-2b", "--variant", "8bit-mixed", *base,
                "--prompt-len", "256", *STREAM]
@@ -246,7 +246,7 @@ def one_chip(seed: int, counter) -> None:
 
 def four_chips(seed: int, counter) -> None:
     yi = ["--arch", "yi-9b", "--train-steps", "0", "--seed", str(seed),
-          "--no-autotune", "--fast", "--variant", "4bit/8bit", "--kv-precision", "int8",
+          "--fast", "--variant", "4bit/8bit", "--kv-precision", "int8",
           "--mesh", "data,model", "--prompt-len", "256", *STREAM]
     tp = serve_phase("yi-9b 1x4 TP", yi + ["--mesh-shape", "1,4"], counter)
     outputs = tp["outputs"]

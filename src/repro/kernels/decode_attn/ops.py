@@ -10,13 +10,15 @@ offset masking (docs/DESIGN.md §11). ``k``/``v`` may be:
 * plain jax.Array            (raw bf16 cache, (B, S, Hkv, hd), or one kv
                               head's flat (B, S, F) rows — MLA's latent)
 
-Backend selection mirrors ``qdot`` (kernels/qmatmul/ops.py):
+Kernel choice mirrors ``qdot`` (kernels/qmatmul/ops.py): the Pallas
+kernel on a TPU in an unsharded trace, else the ``grouped`` jnp fallback
+(the compiler cannot partition a Pallas call over a mesh). Both stream
+the cache in chunks of ``KV_CHUNK`` rows with an online softmax and
+dequantize in-register — no (…, S_max) score tensor is ever materialized
+on the decode path. The per-call ``backend=`` keyword reaches the other
+paths, for tests:
 
-* ``auto``    — (default) the Pallas kernel on TPU in an unsharded
-  trace, else the ``grouped`` jnp fallback (the compiler cannot partition
-  a Pallas call over a mesh). Both stream the cache in KV chunks with an online softmax
-  and dequantize in-register — no (…, S_max) score tensor is ever
-  materialized on the decode path.
+* ``auto``    — (default) the choice above.
 * ``pallas``  — force the Pallas kernel (raises off-TPU rather than
   silently degrading).
 * ``grouped`` — jnp fallback with the kernel's exact math (chunked online
@@ -24,11 +26,8 @@ Backend selection mirrors ``qdot`` (kernels/qmatmul/ops.py):
 * ``simple``  — dequantize-the-cache + dense-softmax oracle (materializes
   the (…, S) scores; parity baseline only).
 
-Set process-wide via ``configure_decode_attn`` (or the
-``REPRO_DECODE_ATTN_BACKEND`` / ``REPRO_DECODE_KV_CHUNK`` env vars, read
-once at import as initial defaults — NOT the same knob as the
-prefill-side ``REPRO_KV_CHUNK`` of models/attention.py). Any chunk width
-works for any cache length: a non-dividing final chunk is read
+The per-call ``kv_chunk=`` (default ``KV_CHUNK``) sets another width.
+Any width works for any cache length: a non-dividing final chunk is read
 clamped/padded and the extra rows are masked out. Both fallbacks are
 validated against ref.py (tests/test_decode_attn.py,
 tests/test_spec_decode.py).
@@ -42,7 +41,6 @@ draft propose k tokens with zero cache writes (docs/DESIGN.md §12).
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -56,34 +54,8 @@ from repro.quant.kvcache import (KVPage, PagedKV, dequantize_kv, quantize_kv,
 
 BACKENDS = ("auto", "pallas", "grouped", "simple")
 NEG_INF = -1e30
-_backend = os.environ.get("REPRO_DECODE_ATTN_BACKEND", "auto")
-_kv_chunk = int(os.environ.get("REPRO_DECODE_KV_CHUNK", "256"))
-
-
-def configure_decode_attn(backend: Optional[str] = None,
-                          kv_chunk: Optional[int] = None) -> None:
-    """Override the decode-attention knobs process-wide (mirrors
-    ``models/attention.configure_chunking``). Read at TRACE time — rebuild
-    jitted executables, or pass ``backend=`` / ``kv_chunk=`` per call, to
-    switch after tracing."""
-    global _backend, _kv_chunk
-    if backend is not None:
-        if backend not in BACKENDS:
-            raise ValueError(f"unknown decode-attn backend {backend!r}; "
-                             f"one of {BACKENDS}")
-        _backend = backend
-    if kv_chunk is not None:
-        if kv_chunk < 1:
-            raise ValueError(f"kv chunk must be >= 1, got {kv_chunk}")
-        _kv_chunk = kv_chunk
-
-
-def get_decode_attn_backend() -> str:
-    return _backend
-
-
-def get_decode_kv_chunk() -> int:
-    return _kv_chunk
+# cache rows a decode-attention step streams per chunk
+KV_CHUNK = 256
 
 
 def _use_pallas() -> bool:
@@ -282,7 +254,7 @@ def _pallas(q, kp, vp, valid, kv_chunk: int, causal: bool,
 def decode_attention(q: jax.Array, k, v, *,
                      valid_len: Optional[jax.Array] = None,
                      causal: bool = True,
-                     backend: Optional[str] = None,
+                     backend: str = "auto",
                      kv_chunk: Optional[int] = None,
                      fresh_kv=None) -> jax.Array:
     """(Multi-)query GQA attention of q (B, S, H, hd) against a cached
@@ -292,8 +264,8 @@ def decode_attention(q: jax.Array, k, v, *,
     query i additionally only sees rows
     ``< valid_len - S + 1 + i`` (S=1 reduces to the plain decode mask),
     with ``causal=False`` every query sees all valid rows (cross-attention
-    over precomputed encoder K/V). ``backend`` overrides the process-wide
-    selection for this call.
+    over precomputed encoder K/V). ``backend`` and ``kv_chunk``: see the
+    module docstring.
 
     ``fresh_kv=(fresh_k, fresh_v, base)`` — raw (B, Sf, Hkv, hd) side
     buffers plus a per-slot (B,) base position: row j acts exactly as if
@@ -301,12 +273,11 @@ def decode_attention(q: jax.Array, k, v, *,
     ``base + j``, and cache rows at positions >= base are masked stale.
     ``valid_len`` still counts ALL valid rows including the fresh ones.
     Returns (B, S, H, hd) in q's dtype."""
-    backend = _backend if backend is None else backend
     if backend not in BACKENDS:
         raise ValueError(f"unknown decode-attn backend {backend!r}; "
                          f"one of {BACKENDS}")
     if kv_chunk is None:
-        kv_chunk = _kv_chunk
+        kv_chunk = KV_CHUNK
     elif kv_chunk < 1:
         raise ValueError(f"kv_chunk must be >= 1, got {kv_chunk}")
     kp, vp = _page_of(k), _page_of(v)

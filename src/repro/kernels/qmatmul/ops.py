@@ -6,28 +6,18 @@ weight matmul. ``w`` may be:
 * a plain jax.Array (raw / bf16 path)          -> einsum
 * a QTensor (int8 / int4 / ternary)            -> fused dequant matmul
 
-Backend selection (explicit, per-call or process-wide):
-
-* ``auto``    — (default) the Pallas kernel on TPU when the shape is one
-  the kernel takes and the trace is not sharded over a mesh (the compiler
-  cannot partition a Pallas call), else the ``simple`` jnp fallback. XLA
-  fuses the fallback reasonably, keeping HLO byte counts faithful to
-  weight-only quantization (int8/int4 weights are read at their quantized
-  width; dequant is a flop-cheap broadcast-multiply).
-* ``pallas``  — force the Pallas kernel (raises off-TPU / on shapes the
-  kernel does not take rather than silently degrading).
-* ``grouped`` — jnp fallback with the kernel's exact math: per-group
-  partial sums are scaled, never materializing a dequantized weight.
-* ``simple``  — dequantize-then-dot fallback.
-
-Set process-wide via ``set_qdot_backend`` or the ``REPRO_QDOT_BACKEND``
-env var; both jnp fallbacks are validated against ref.py
-(tests/test_compiler.py::test_qdot_backends).
-
-Which shapes the kernels take depends on M, the activation rows:
+Kernel choice: a function of the platform, the trace and the call's
+shapes alone. The Pallas kernels run on a TPU, in a trace that is not
+sharded over a mesh (the compiler cannot partition a Pallas call), where
+the shape is one the kernel takes; everywhere else the ``simple`` jnp
+fallback runs. XLA fuses the fallback reasonably, keeping HLO byte counts
+faithful to weight-only quantization (int8/int4 weights are read at their
+quantized width; dequant is a flop-cheap broadcast-multiply). Which
+shapes the kernels take, and with which blocks, depends on M, the
+activation rows:
 
 * prefill (M >= ``DECODE_M``): tile-aligned shapes (``_pallas_aligned``)
-  with the kernels' default blocks or the ``configure_qmatmul`` ones.
+  with the kernels' default blocks.
 * decode (M < ``DECODE_M``: the slot count, or slots x (k+1) in a
   speculative round): the call is bound by the weight's bytes, so M is
   padded to the bf16 sublane tile (``SUBLANE``) and the blocks stream
@@ -35,6 +25,18 @@ Which shapes the kernels take depends on M, the activation rows:
   admits them: N a multiple of 128 and a K block that holds whole scale
   groups on a packed lane dim of 128s (``qdot``/``fused_qkv``), an FF
   block of 256s that divides FF (``fused_mlp``).
+
+The per-call ``backend=`` keyword reaches the other paths, for tests:
+
+* ``auto``    — (default) the choice above.
+* ``pallas``  — force the Pallas kernel (raises off-TPU / on shapes the
+  kernel does not take rather than silently degrading).
+* ``grouped`` — jnp fallback with the kernel's exact math: per-group
+  partial sums are scaled, never materializing a dequantized weight.
+* ``simple``  — dequantize-then-dot fallback.
+
+Both jnp fallbacks are validated against ref.py
+(tests/test_compiler.py::test_qdot_backends).
 
 Each dispatch of a quantized weight counts once, at trace time, into
 ``ewq_qmatmul_calls_total{path="pallas"|"dequant",
@@ -45,7 +47,6 @@ regime="decode"|"prefill"|"expert"}`` on the installed metrics registry
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -58,60 +59,6 @@ from repro.kernels.qmatmul.kernel import (DEFAULT_BK, DEFAULT_BM, DEFAULT_BN,
                                           qmlp_pallas)
 
 BACKENDS = ("auto", "pallas", "grouped", "simple")
-_backend = os.environ.get("REPRO_QDOT_BACKEND", "auto")
-# Pallas block-shape overrides (None -> kernel defaults); set by
-# configure_qmatmul, swept by kernels/autotune.py. Read at TRACE time.
-_blocks: dict = {"bm": None, "bn": None, "bk": None}
-
-
-def configure_qmatmul(bm: int | None = None, bn: int | None = None,
-                      bk: int | None = None,
-                      backend: str | None = None) -> None:
-    """Override the Pallas qmatmul/megakernel block shapes (and optionally
-    the backend) process-wide — the autotuner's hook (kernels/autotune.py).
-    Read at TRACE time like ``set_qdot_backend``; blocks that do not divide
-    a particular call's shape fall back to the kernel defaults for that
-    call."""
-    global _blocks
-    for name, val in (("bm", bm), ("bn", bn), ("bk", bk)):
-        if val is not None:
-            if val < 128 or val % 128:
-                raise ValueError(f"{name} must be a multiple of 128, "
-                                 f"got {val}")
-            _blocks[name] = val
-    if backend is not None:
-        set_qdot_backend(backend)
-
-
-def get_qmatmul_blocks() -> dict:
-    return dict(_blocks)
-
-
-def _block_kwargs(m: int, n: int, k: int) -> dict:
-    """Tuned block overrides that actually divide this call's shape."""
-    kw = {}
-    for name, dim in (("bm", m), ("bn", n), ("bk", k)):
-        v = _blocks[name]
-        if v is not None and dim % min(v, dim) == 0:
-            kw[name] = v
-    return kw
-
-
-def set_qdot_backend(name: str) -> None:
-    """Select the process-wide default qdot backend (see module docstring).
-
-    The selection is read at TRACE time: functions jitted before the call
-    (e.g. a ServeEngine's cached decode/prefill executables) keep the
-    backend they were traced with — rebuild them (or pass ``backend=`` per
-    call) to switch."""
-    if name not in BACKENDS:
-        raise ValueError(f"unknown qdot backend {name!r}; one of {BACKENDS}")
-    global _backend
-    _backend = name
-
-
-def get_qdot_backend() -> str:
-    return _backend
 
 
 def _use_pallas() -> bool:
@@ -148,19 +95,16 @@ DECODE_BF = (512, 256)
 DECODE_STEP_BYTES = 8 * 2**20
 
 
-def _pick(name: str, candidates: tuple, fits) -> int | None:
-    """The ``configure_qmatmul`` override ``name`` where it fits, else the
-    first candidate that does."""
-    v = _blocks[name]
-    return next((c for c in ((v,) if v else ()) + candidates if fits(c)),
-                None)
+def _pick(candidates: tuple, fits) -> int | None:
+    """The first candidate that fits."""
+    return next((c for c in candidates if fits(c)), None)
 
 
 def _decode_bk(k: int, rows: int, w: QTensor) -> int | None:
     """K block of a decode call: divides K, holds whole scale groups, has
     a stored lane dim of 128s, and keeps ``rows`` weight rows of it
     within one step's bytes."""
-    return _pick("bk", DECODE_BK, lambda bk: (
+    return _pick(DECODE_BK, lambda bk: (
         k % bk == 0 and bk % w.group == 0
         and _lanes(bk, w.precision) % 128 == 0
         and rows * _lanes(bk, w.precision) <= DECODE_STEP_BYTES))
@@ -176,7 +120,7 @@ def _decode_blocks(kernel: str, m: int, k: int, ns: tuple,
         ff, d = ns
         if k % w.group or _lanes(k, w.precision) % 128 or d % 128:
             return None
-        bf = _pick("bn", DECODE_BF, lambda bf: (
+        bf = _pick(DECODE_BF, lambda bf: (
             ff % bf == 0 and bf % w.group == 0
             and _lanes(bf, w.precision) % 128 == 0))
         return None if bf is None else {"bm": bm, "bf": bf}
@@ -185,7 +129,7 @@ def _decode_blocks(kernel: str, m: int, k: int, ns: tuple,
     if kernel == "qkv":
         bk = _decode_bk(k, sum(ns), w)
         return None if bk is None else {"bm": bm, "bk": bk}
-    bn = _pick("bn", DECODE_BN, lambda bn: ns[0] % bn == 0)
+    bn = _pick(DECODE_BN, lambda bn: ns[0] % bn == 0)
     bk = _decode_bk(k, bn, w)
     return None if bk is None else {"bm": bm, "bn": bn, "bk": bk}
 
@@ -210,21 +154,18 @@ def count_dispatch(path: str, m: int, regime: str | None = None) -> None:
 def _prefill_blocks(kernel: str, m: int, k: int, ns: tuple,
                     w: QTensor) -> dict | None:
     """Blocks of an M >= 128 call (``ns`` as in ``_decode_blocks``): the
-    kernel defaults or the ``configure_qmatmul`` ones, where every weight
-    it reads is tile aligned."""
+    kernel defaults, where every weight it reads is tile aligned."""
     aligned = functools.partial(_pallas_aligned, m, precision=w.precision)
     if kernel == "qmlp":
         ff, d = ns
         if not (aligned(ff, k) and d % 128 == 0 and aligned(d, ff)):
             return None
-        bk = _block_kwargs(m, ff, k)
-        return {"bm": bk.get("bm", DEFAULT_BM), "bf": bk.get("bn", DEFAULT_BN)}
+        return {"bm": DEFAULT_BM, "bf": DEFAULT_BN}
     if not all(aligned(n, k) for n in ns):
         return None
-    bk = _block_kwargs(m, ns[0], k)
     if kernel == "qkv":
-        return {"bm": bk.get("bm", DEFAULT_BM), "bk": bk.get("bk", DEFAULT_BK)}
-    return bk
+        return {"bm": DEFAULT_BM, "bk": DEFAULT_BK}
+    return {}
 
 
 def _kernel_blocks(backend: str, kernel: str, m: int, k: int, ns: tuple,
@@ -282,12 +223,10 @@ def _dequant_simple(x2d: jax.Array, w: QTensor) -> jax.Array:
                                preferred_element_type=jnp.float32)
 
 
-def qdot(x: jax.Array, w, out_dtype=None, backend: str | None = None
+def qdot(x: jax.Array, w, out_dtype=None, backend: str = "auto"
          ) -> jax.Array:
-    """y[..., n] = sum_k x[..., k] * W[n, k] with W possibly quantized.
-
-    ``backend`` overrides the process-wide selection for this call."""
-    backend = backend or _backend
+    """y[..., n] = sum_k x[..., k] * W[n, k] with W possibly quantized
+    (``backend``: see the module docstring)."""
     if backend not in BACKENDS:
         raise ValueError(f"unknown qdot backend {backend!r}; "
                          f"one of {BACKENDS}")
@@ -332,14 +271,13 @@ def _out_dim(w) -> int:
 
 
 def fused_mlp(x: jax.Array, w_gate, w_up, w_down, act: str = "swiglu",
-              backend: str | None = None) -> jax.Array:
+              backend: str = "auto") -> jax.Array:
     """Whole quantized MLP block in one call: on TPU with a shape the
     kernel takes, a single Pallas launch where the (M, FF) hidden
     activation never reaches HBM and no bf16 weight copy ever exists;
     everywhere else the EXACT qdot sequence of models/mlp.py
     (bit-identical fallback — greedy serving output does not depend on
     which path ran). ``w_gate`` is None for act="gelu"."""
-    backend = backend or _backend
     if backend not in BACKENDS:
         raise ValueError(f"unknown qdot backend {backend!r}; "
                          f"one of {BACKENDS}")
@@ -371,12 +309,11 @@ def fused_mlp(x: jax.Array, w_gate, w_up, w_down, act: str = "swiglu",
     return qdot(h, w_down, backend=backend)
 
 
-def fused_qkv(x: jax.Array, wq, wk, wv, backend: str | None = None):
+def fused_qkv(x: jax.Array, wq, wk, wv, backend: str = "auto"):
     """The three attention projections in one launch: each activation tile
     is read from HBM once and feeds all three accumulators. Fallback is
     exactly three ``qdot`` calls (bit-identical). Returns (q, k, v) with
     qdot's dtype convention."""
-    backend = backend or _backend
     if backend not in BACKENDS:
         raise ValueError(f"unknown qdot backend {backend!r}; "
                          f"one of {BACKENDS}")

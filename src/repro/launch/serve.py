@@ -115,9 +115,6 @@ def main(argv=None) -> dict:
     ap.add_argument("--num-layers", type=int, default=0,
                     help="cut the config's depth to N layers (0: published "
                          "depth); widths are never changed")
-    ap.add_argument("--no-autotune", action="store_true",
-                    help="keep the kernels' default tiles: do not read the "
-                         "autotune cache (kernels/autotune.py)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
@@ -391,7 +388,6 @@ def main(argv=None) -> dict:
             return ServeEngine.from_artifact(model, args.plan_artifact,
                                              max_seq=max_seq, mesh=m,
                                              spec=spec, paged=paged,
-                                             autotune=not args.no_autotune,
                                              **kv_kw)
 
         engine, replica = build_engines(make_engine)
@@ -436,8 +432,7 @@ def main(argv=None) -> dict:
                 e = ServeEngine(model, compiled.params, max_seq=max_seq,
                                 mesh=m,
                                 kv_precision=compiled.kv_plan or "bf16",
-                                spec=spec, paged=paged,
-                                autotune=not args.no_autotune)
+                                spec=spec, paged=paged)
                 e.plan = plan
                 return e
 
@@ -454,8 +449,7 @@ def main(argv=None) -> dict:
             def make_engine(m):
                 return ServeEngine(model, params, max_seq=max_seq, mesh=m,
                                    kv_precision=kv_precision, spec=spec,
-                                   paged=paged,
-                                   autotune=not args.no_autotune)
+                                   paged=paged)
 
             engine, replica = build_engines(make_engine)
 

@@ -9,7 +9,6 @@ Optionally q_norm / k_norm RMS weights (chameleon-style QK-norm).
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -22,26 +21,11 @@ from repro.quant import kvcache as KV
 from repro.sharding.ctx import constrain, model_shards, unroll_flag
 
 NEG_INF = -1e30
-# Chunked-attention knobs, overridable per process via env
-# (REPRO_CHUNK_THRESHOLD / REPRO_Q_CHUNK / REPRO_KV_CHUNK) or
-# ``configure_chunking`` — read at TRACE time, so set them before jitting.
-CHUNK_THRESHOLD = int(os.environ.get("REPRO_CHUNK_THRESHOLD", "8192"))
-Q_CHUNK = int(os.environ.get("REPRO_Q_CHUNK", "2048"))
-KV_CHUNK = int(os.environ.get("REPRO_KV_CHUNK", "2048"))
-
-
-def configure_chunking(chunk_threshold: Optional[int] = None,
-                       q_chunk: Optional[int] = None,
-                       kv_chunk: Optional[int] = None) -> None:
-    """Override the chunked-attention thresholds process-wide (functions
-    jitted before the call keep the values they were traced with)."""
-    global CHUNK_THRESHOLD, Q_CHUNK, KV_CHUNK
-    for name, val in (("CHUNK_THRESHOLD", chunk_threshold),
-                      ("Q_CHUNK", q_chunk), ("KV_CHUNK", kv_chunk)):
-        if val is not None:
-            if val < 1:
-                raise ValueError(f"{name} must be >= 1, got {val}")
-            globals()[name] = val
+# Prefill attention over more than CHUNK_THRESHOLD tokens runs the
+# online-softmax chunked form, in Q_CHUNK x KV_CHUNK blocks.
+CHUNK_THRESHOLD = 8192
+Q_CHUNK = 2048
+KV_CHUNK = 2048
 
 
 class KVCache(NamedTuple):

@@ -4,10 +4,9 @@
 Everything is OFF by default. The serving stack emits through the
 module-level helpers below; with nothing installed each call is one
 ``None`` check and an immediate return — the same disabled-path
-discipline as ``serving/chaos.py``, budgeted at <1% serve throughput
-(``benchmarks/serve_throughput.py`` ``serve/obs/*`` rows keep it
-honest). Hot per-tick paths hold the ``tracer()`` handle once and branch
-on it so even the argument packing is skipped when tracing is off.
+discipline as ``serving/chaos.py``. Hot per-tick paths hold the
+``tracer()`` handle once and branch on it so even the argument packing
+is skipped when tracing is off.
 
 Usage::
 

@@ -80,8 +80,6 @@ SCHEMA = {
         ("gauge", "tokens per KV page"),
     "serve_kv_bytes_peak":
         ("gauge", "peak physical KV bytes held"),
-    "serve_tuned_info":
-        ("gauge", "autotune cache key the engine was traced under"),
     "serve_ttft_seconds":
         ("histogram", "time to first token (dequeue -> first token)"),
     "serve_tpot_seconds":
@@ -128,7 +126,6 @@ STATS_FIELD_METRICS = {
     "prefix_hit_rate": "serve_prompt_tokens_total",
     "cow_copies": "serve_cow_copies_total",
     "kv_bytes_peak": "serve_kv_bytes_peak",
-    "tuned": "serve_tuned_info",
     "replica_restarts": "serve_replica_restarts_total",
     "redriven_requests": "serve_redriven_requests_total",
     "recovery_p95_s": "serve_recovery_seconds",
@@ -157,7 +154,7 @@ def publish_session(reg, *, replica: int, outputs, occupancy: float,
                     spec_m: dict, spec_labels: Optional[dict],
                     watchdog_trips: int, degraded_steps: int,
                     transitions: int, tier_steps, tier_labels,
-                    tuned: str, pool: Optional[dict] = None,
+                    pool: Optional[dict] = None,
                     recovery=(), restarts: int = 0,
                     redriven: int = 0) -> None:
     """Write one serve run into ``reg``. ``outputs`` are RequestOutputs
@@ -173,7 +170,6 @@ def publish_session(reg, *, replica: int, outputs, occupancy: float,
     _c(reg, "serve_degraded_steps_total").inc(degraded_steps, replica=r)
     _c(reg, "serve_degrade_transitions_total").inc(transitions, replica=r)
     _g(reg, "serve_occupancy_ratio").set(occupancy, replica=r)
-    _g(reg, "serve_tuned_info").set(1.0, key=tuned, replica=r)
     tiers = _c(reg, "serve_kv_tier_steps_total")
     for i, steps in enumerate(tier_steps):
         label = (tier_labels[i] if tier_labels is not None
@@ -259,12 +255,6 @@ def stats_fields(reg) -> dict:
         v = m.value(**labels)
         return v if v is not None else m.total()
 
-    tuned = "untuned"
-    m = reg.get("serve_tuned_info")
-    if m is not None:
-        keys = m.labeled("key")
-        if keys:
-            tuned = sorted(keys)[0]
     tiers: tuple = ()
     m = reg.get("serve_kv_tier_steps_total")
     if m is not None:
@@ -314,7 +304,6 @@ def stats_fields(reg) -> dict:
                          if prompt_tokens else 0.0),
         cow_copies=int(reg.total("serve_cow_copies_total")),
         kv_bytes_peak=float(pool_gauge("serve_kv_bytes_peak")),
-        tuned=tuned,
         replica_restarts=int(reg.total("serve_replica_restarts_total")),
         redriven_requests=int(reg.total("serve_redriven_requests_total")),
         recovery_p95_s=(rec.quantile(95) if rec is not None

@@ -15,8 +15,7 @@ Track mapping:
   ``replica/failover`` spans and ``degrade/transition`` / chaos
   instants.
 * ``tid 1`` = the DECODE track: one ``decode/chunk`` X-span per launched
-  chunk (dispatch -> harvest wall; args carry the tier and the autotune
-  stamp).
+  chunk (dispatch -> harvest wall; args carry the steps and the tier).
 * ``tid REQ_TRACK_BASE + rid`` = one track per REQUEST: its
   ``request/queued`` → ``request/prefill`` → ``request/decode`` phases
   are strictly sequential, so they form balanced B/E pairs; phase

@@ -597,13 +597,7 @@ def save_artifact(directory: str, compiled: CompiledPlan,
     none. ``mesh`` only stamps the save-time layout into the manifest for
     provenance."""
     from repro.checkpoint import ckpt
-    from repro.kernels.autotune import current_stamp
     manifest = compiled.manifest()
-    # which kernel-tuning config (kernels/autotune.py) was live when the
-    # artifact was produced — "untuned" for library defaults. Cold-booted
-    # replicas re-resolve against their own device's cache; this records
-    # provenance for the numbers benchmarked at save time.
-    manifest["autotune"] = current_stamp()
     if mesh is not None:
         manifest["saved_mesh"] = {
             "axis_names": list(mesh.axis_names),

@@ -6,8 +6,8 @@ Deployment story (the paper's §3.4/§4 pipeline, end-to-end):
   2. quantize params per plan (block-granular mixed precision);
   3. serve: prefill fills the KV/SSM cache, decode runs against quantized
      weights (decode is weight-bytes-bound — exactly where int8/int4
-     payloads pay off; see README.md §Serving and
-     benchmarks/serve_throughput.py).
+     payloads pay off; see README.md §Serving, and PERF.md for what the
+     chip benchmark under bench/ measures).
 
 Engine structure:
   * the decode loop is ONE jitted ``lax.scan`` over a chunk of token steps
@@ -122,7 +122,7 @@ class ChunkedPrefill:
 
 @dataclasses.dataclass
 class ServeStats:
-    """Continuous-batching run statistics (benchmarks/serve_throughput.py)."""
+    """Continuous-batching run statistics."""
     decode_steps: int          # jitted decode steps executed (chunks * chunk)
     generated_tokens: int      # tokens actually emitted across all requests
     occupancy: float           # mean fraction of active slots per chunk
@@ -161,9 +161,6 @@ class ServeStats:
     prefix_hit_rate: float = 0.0   # hit tokens / total prompt tokens
     cow_copies: int = 0            # COW boundary pages materialized
     kv_bytes_peak: float = 0.0     # peak physical KV bytes actually held
-    # kernels/autotune.py provenance: the tune-cache key whose config the
-    # engine's executables were traced under, or "untuned"
-    tuned: str = "untuned"
     # fault tolerance + graceful degradation (docs/DESIGN.md §15)
     replica_restarts: int = 0      # replicas quarantined and failed over
     redriven_requests: int = 0     # in-flight requests re-driven to survivors
@@ -195,9 +192,17 @@ class ServeEngine:
                  mesh=None, kv_precision="bf16",
                  kv_group: Optional[int] = None,
                  spec: Optional[SpecConfig] = None,
-                 autotune: bool = True,
+                 autotune: bool = False,
                  paged=None,
                  prefill_chunk: Optional[int] = None):
+        """``autotune`` accepts only False: kernels are chosen from the
+        platform and the shapes alone (kernels/qmatmul/ops.py). The keyword
+        stays only until the benchmark harness stops passing it, and leaves
+        with the next change to the benchmark."""
+        if autotune:
+            raise ValueError("autotune=True: the kernel autotuner is gone; "
+                             "kernels are chosen from the platform and the "
+                             "shapes alone")
         self.model = model
         self.cfg = model.cfg
         self.max_seq = max_seq
@@ -242,16 +247,6 @@ class ServeEngine:
                                     serving_param_shardings(params, mesh))
         self.params = params
         self.kv_plan = self._resolve_kv_plan(kv_precision, kv_group)
-        # kernels/autotune.py: swap in the tuned chunk/tile config (if one
-        # is cached for this device/family/precision) BEFORE the jitted
-        # paths below trace — every knob is read at trace time. "untuned"
-        # means library defaults; the stamp lands in ServeStats and saved
-        # artifact manifests for provenance.
-        self.tuned = "untuned"
-        if autotune:
-            from repro.kernels.autotune import kv_label, maybe_apply_tuned
-            self.tuned = maybe_apply_tuned(self.cfg.family,
-                                           kv_label(self.kv_plan))
         self._decode = self._traced(jax.jit(model.decode_step))
         # built once, cached (enc-dec prefill also takes encoder frames)
         self._prefill = self._traced(jax.jit(self._prefill_encdec
@@ -1093,11 +1088,9 @@ class ServeEngine:
                           key: Optional[jax.Array] = None,
                           frames: Optional[jax.Array] = None
                           ) -> GenerateResult:
-        """Legacy per-token Python dispatch loop.
-
-        Kept as the benchmark baseline (benchmarks/serve_throughput.py):
-        identical math to ``generate``, but every token pays Python-side
-        sampling-op dispatch plus a separate jitted decode dispatch.
+        """Per-token Python dispatch loop: the test oracle of ``generate``
+        (tests/test_serving.py), with identical math but one jitted decode
+        dispatch and Python-side sampling per token.
         """
         b = prompts.shape[0]
         cache, last_logits = self.prefill(prompts, frames)

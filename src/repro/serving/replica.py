@@ -309,7 +309,6 @@ def _merge_stats(outputs: list, per_replica: list[ServeStats]) -> ServeStats:
         prefix_hit_tokens=sum(st.prefix_hit_tokens for st in per_replica),
         cow_copies=sum(st.cow_copies for st in per_replica),
         kv_bytes_peak=sum(st.kv_bytes_peak for st in per_replica),
-        tuned=per_replica[0].tuned if per_replica else "untuned",
         watchdog_trips=sum(st.watchdog_trips for st in per_replica),
         degraded_steps=sum(st.degraded_steps for st in per_replica),
         degrade_transitions=sum(st.degrade_transitions

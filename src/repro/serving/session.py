@@ -267,7 +267,7 @@ class ServeSession:
             return
         chaos.fire("replica.harvest", tag=self.replica_id)
         self._dispatched = False
-        eng, sched = self.engine, self.sched
+        sched = self.sched
         with obs.span("serve/readback", self.replica_id):
             if self._pending_spec is not None:
                 delta = {k_: int(v)
@@ -287,8 +287,7 @@ class ServeSession:
             if tr is not None:
                 tr.complete("decode/chunk", tr.now_us() - gap * 1e6,
                             self.replica_id, obs.DECODE_TRACK,
-                            args={"steps": self.chunk, "tier": self.tier,
-                                  "tuned": eng.tuned})
+                            args={"steps": self.chunk, "tier": self.tier})
             if self.watchdog_s is not None and gap > self.watchdog_s:
                 # dispatch->harvest deadline overrun: an in-process stall
                 # cannot be preempted, so it is surfaced (ServeStats
@@ -534,7 +533,7 @@ class ServeSession:
             transitions=len(self.transitions),
             tier_steps=self.tier_steps,
             tier_labels=kv_tier_labels(self._ladder),
-            tuned=eng.tuned, pool=pool_kw)
+            pool=pool_kw)
         installed = obs.metrics()
         if installed is not None:
             installed.merge(local)

@@ -11,6 +11,7 @@ Covers the compiler contract (docs/DESIGN.md §8):
 """
 
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config
-from repro.kernels.qmatmul.ops import get_qdot_backend, qdot, set_qdot_backend
+from repro.kernels.qmatmul.ops import qdot
 from repro.kernels.qmatmul.ref import qmatmul_ref
 from repro.models.model import build
 from repro.quant.apply import (SegmentedParams, apply_plan_blocks,
@@ -213,7 +214,14 @@ def test_artifact_roundtrip_dense_all_precisions(tmp_path):
     compiled = compile_plan(model, params, plan)
     save_artifact(str(tmp_path), compiled)
     loaded = load_artifact(str(tmp_path), model)
-    assert (tmp_path / "plan_manifest.json").exists()
+    mpath = tmp_path / "plan_manifest.json"
+    assert mpath.exists()
+    # manifests written with the former kernel-tuning stamp still load
+    manifest = json.loads(mpath.read_text())
+    assert "autotune" not in manifest
+    mpath.write_text(json.dumps({**manifest, "autotune": "untuned"}))
+    assert load_artifact(str(tmp_path),
+                         model).plan.precisions() == plan.precisions()
     assert loaded.plan.precisions() == plan.precisions()
     assert loaded.nbytes_effective() == compiled.nbytes_effective()
     precisions = {s.precision for s in loaded.params["layers"].segments}
@@ -339,9 +347,6 @@ def test_qdot_backends_match_ref(precision):
 
 
 def test_qdot_backend_selection_and_errors():
-    assert get_qdot_backend() == "auto"
-    with pytest.raises(ValueError):
-        set_qdot_backend("nope")
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 256))
     w = quantize(jax.random.normal(jax.random.PRNGKey(2), (16, 256)), "int8")
     with pytest.raises(ValueError):
@@ -349,10 +354,6 @@ def test_qdot_backend_selection_and_errors():
     if jax.default_backend() != "tpu":
         with pytest.raises(ValueError):  # forced pallas off-TPU: loud, not silent
             qdot(x, w, backend="pallas")
-    set_qdot_backend("grouped")
-    try:
-        y = np.asarray(qdot(x, w, out_dtype=jnp.float32))
-        np.testing.assert_allclose(y, np.asarray(qmatmul_ref(x, w)),
-                                   rtol=2e-2, atol=1e-2)
-    finally:
-        set_qdot_backend("auto")
+    y = np.asarray(qdot(x, w, out_dtype=jnp.float32, backend="grouped"))
+    np.testing.assert_allclose(y, np.asarray(qmatmul_ref(x, w)),
+                               rtol=2e-2, atol=1e-2)

@@ -329,13 +329,36 @@ def test_pallas_aligned_accounts_for_int4_packing():
     assert _pallas_aligned(128, 128, 1024, "int4")
 
 
-def test_chunking_knobs_configurable():
+@pytest.mark.parametrize("family,arch", FAMILY_ARCHS)
+def test_chunk_widths_never_change_greedy_output(family, arch, monkeypatch):
+    """Decode-attention and prefill chunk widths (odd, non-dividing ones
+    included) reschedule attention; the greedy tokens stay those of the
+    default widths on every family."""
+    from repro.kernels.decode_attn import ops as dops
     from repro.models import attention as A
-    old = (A.CHUNK_THRESHOLD, A.Q_CHUNK, A.KV_CHUNK)
+    cfg = get_config(arch, smoke=True)
+    cfg = dataclasses.replace(
+        cfg, num_layers=4 if cfg.family == "hybrid" else 2)
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    prompts = jax.random.randint(jax.random.PRNGKey(2), (2, 6), 0,
+                                 cfg.vocab_size)
+    frames = None
+    if cfg.family == "encdec":
+        frames = jax.random.normal(jax.random.PRNGKey(5),
+                                   (2, cfg.encoder_seq, cfg.d_model))
+
+    def greedy():
+        jax.clear_caches()   # retrace: the widths are read while tracing
+        eng = ServeEngine(model, params, max_seq=24, kv_precision="int8")
+        return np.asarray(eng.generate(prompts, 8, frames=frames).tokens)
+
+    base = greedy()
+    monkeypatch.setattr(dops, "KV_CHUNK", 3)
+    monkeypatch.setattr(A, "CHUNK_THRESHOLD", 4)
+    monkeypatch.setattr(A, "Q_CHUNK", 4)
+    monkeypatch.setattr(A, "KV_CHUNK", 8)
     try:
-        A.configure_chunking(chunk_threshold=16, q_chunk=8, kv_chunk=8)
-        assert (A.CHUNK_THRESHOLD, A.Q_CHUNK, A.KV_CHUNK) == (16, 8, 8)
-        with pytest.raises(ValueError):
-            A.configure_chunking(q_chunk=0)
+        np.testing.assert_array_equal(greedy(), base)
     finally:
-        A.configure_chunking(*old)
+        jax.clear_caches()   # no later trace reuses the odd widths

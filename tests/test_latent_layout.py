@@ -116,13 +116,13 @@ def test_headed_pages_keep_their_layout():
         assert (one.num_kv_heads, one.seq_len) == (4, S)
     yi = build(get_config("yi-9b", smoke=True))
     eng = ServeEngine(yi, yi.init(jax.random.PRNGKey(0)), max_seq=32,
-                      kv_precision="int8", autotune=False)
+                      kv_precision="int8")
     for k in _page_list(eng.init_decode_state(2).cache.k):
         assert not k.flat and k.data.shape[1:] == (
             2, 32, yi.cfg.num_kv_heads, yi.cfg.head_dim)
     mla = build(CFG)
     eng = ServeEngine(mla, mla.init(jax.random.PRNGKey(0)), max_seq=32,
-                      kv_precision="int8", autotune=False)
+                      kv_precision="int8")
     pages = _page_list(eng.init_decode_state(2).cache.c)
     assert sum(c.data.shape[0] for c in pages) == CFG.num_layers
     for c in pages:
@@ -147,11 +147,9 @@ def test_decode_attention_flat_matches_headed(backend, precision):
     _same(got, dops.decode_attention(q, view, view, **kw))
 
 
-def _serve(params, kv, backend):
-    dops.configure_decode_attn(backend=backend)
+def _serve(params, kv):
     jax.clear_caches()
-    eng = ServeEngine(build(CFG), params, max_seq=32, kv_precision=kv,
-                      autotune=False)
+    eng = ServeEngine(build(CFG), params, max_seq=32, kv_precision=kv)
     rng = np.random.default_rng(1)
     reqs = [Request(rid=i, prompt=rng.integers(0, CFG.vocab_size, n)
                     .astype(np.int32), max_new_tokens=m)
@@ -167,18 +165,21 @@ def test_served_tokens_match_headed_view(monkeypatch, backend, kv):
     """Continuous batching (per-slot positions, slots released and
     reused) serves the same tokens and logprobs, bit for bit, whether
     decode attention reads the flat cache or its headed view."""
-    was = dops.get_decode_attn_backend()
+    if backend == "simple":   # the jnp fallback the engine takes, swapped
+        monkeypatch.setattr(
+            dops, "_grouped",
+            lambda q, kp, vp, valid, kv_chunk, causal, fresh:
+            dops._simple(q, kp, vp, valid, causal, fresh))
     params = build(CFG).init(jax.random.PRNGKey(0))
     try:
-        tokens, logprobs = _serve(params, kv, backend)
+        tokens, logprobs = _serve(params, kv)
         attend = MLA.decode_attention
         monkeypatch.setattr(
             MLA, "decode_attention",
             lambda q, k, v, **kw: attend(q, _headed_view(k),
                                          _headed_view(v), **kw))
-        want_tokens, want_logprobs = _serve(params, kv, backend)
+        want_tokens, want_logprobs = _serve(params, kv)
     finally:
-        dops.configure_decode_attn(backend=was)
         jax.clear_caches()
     for got, want in zip(tokens + logprobs, want_tokens + want_logprobs):
         _same(got, want)
@@ -190,7 +191,7 @@ def test_slot_round_trip_through_release_and_reuse():
     request, that request decodes exactly as in a slot never used."""
     model = build(CFG)
     eng = ServeEngine(model, model.init(jax.random.PRNGKey(0)), max_seq=32,
-                      kv_precision="int8", autotune=False)
+                      kv_precision="int8")
     rng = np.random.default_rng(2)
     a = eng.prefill_request(rng.integers(0, CFG.vocab_size, 9))
     b = eng.prefill_request(rng.integers(0, CFG.vocab_size, 5))

@@ -22,10 +22,9 @@ def _chip_smoke():
     return mod
 
 
-STREAM = ["--smoke", "--train-steps", "0", "--no-autotune",
-          "--kv-precision", "int8", "--prompt-len", "16",
-          "--num-requests", "4", "--num-slots", "2", "--chunk", "4",
-          "--max-new", "6"]
+STREAM = ["--smoke", "--train-steps", "0", "--kv-precision", "int8",
+          "--prompt-len", "16", "--num-requests", "4", "--num-slots", "2",
+          "--chunk", "4", "--max-new", "6"]
 
 
 @pytest.fixture(scope="module")

@@ -195,7 +195,7 @@ def test_decode_through_latent_cache_matches_reference(kv):
            f"decode through the {kv} latent cache")
 
 
-def test_quantized_program_matches_reference_qdq():
+def test_quantized_program_matches_reference_qdq(monkeypatch):
     """The FastEWQ plan of a 5-layer stack (int8 layer 3, int4 layer 4, as
     in the benchmark's stage) compiled by the program against the
     reference's own quantizer; the program's jnp path keeps float32 where
@@ -209,13 +209,10 @@ def test_quantized_program_matches_reference_qdq():
     assert plan.precisions() == ["raw"] * 4 + ["int8", "int4"]
     pq = model.compile_plan(p, plan).params
     toks = _tokens(32, seed=5)
-    was = qops.get_qdot_backend()
-    qops.set_qdot_backend("grouped")
-    try:
-        lg, _, _ = T.apply(pq, jnp.asarray(toks)[None], cfg, remat=False,
-                           return_cache=True)
-    finally:
-        qops.set_qdot_backend(was)
+    # the kernel's exact math in place of the dequantize-then-dot fallback
+    monkeypatch.setattr(qops, "_dequant_simple", qops._dequant_fused)
+    lg, _, _ = T.apply(pq, jnp.asarray(toks)[None], cfg, remat=False,
+                       return_cache=True)
     ref, _ = R.Reference(p, conf, plan.precisions()).forward(toks)
     _close(lg[0, :, :cfg.vocab_size], ref, F32_TOL, "quantized program")
     raw = _reference(p, cfg).logits(toks)
@@ -310,15 +307,14 @@ def test_serving_refuses_paged_and_speculative():
     p = model.init(jax.random.PRNGKey(0))
     for kw in (dict(paged=True), dict(spec=SpecConfig(k=2))):
         with pytest.raises(ValueError, match="latent"):
-            ServeEngine(model, p, max_seq=64, autotune=False, **kw)
+            ServeEngine(model, p, max_seq=64, **kw)
 
 
 def test_engine_serves_int8_latent_cache():
     from repro.serving.engine import ServeEngine
     model = build(CFG)
     p = _params(bias=FIXED)
-    eng = ServeEngine(model, p, max_seq=48, kv_precision="int8",
-                      autotune=False)
+    eng = ServeEngine(model, p, max_seq=48, kv_precision="int8")
     assert eng.kv_bytes_per_slot() == CFG.num_layers * 48 * (
         CFG.latent_dim + CFG.latent_dim // 64 * 2)
     toks = _tokens(12, seed=7)

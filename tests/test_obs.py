@@ -453,7 +453,7 @@ def test_publish_stats_fields_round_trip():
                                        committed=25),
         spec_labels={"k": "2", "source": "self"}, watchdog_trips=1,
         degraded_steps=8, transitions=2, tier_steps=(12, 8),
-        tier_labels=["bf16", "int8"], tuned="dense/int8",
+        tier_labels=["bf16", "int8"],
         pool=dict(pages_total=6, pages_peak=5, page_size=8, prefix_hits=2,
                   prefix_hit_tokens=12, prompt_tokens=24, cow_copies=1,
                   kv_bytes_peak=4096.0),
@@ -474,7 +474,6 @@ def test_publish_stats_fields_round_trip():
     assert f["pool_page_size"] == 8 and f["cow_copies"] == 1
     assert f["prefix_hit_rate"] == pytest.approx(12 / 24)
     assert f["kv_bytes_peak"] == 4096.0
-    assert f["tuned"] == "dense/int8"
     assert f["kv_tier_steps"] == (12, 8)
     assert f["degraded_steps"] == 8 and f["degrade_transitions"] == 2
     assert f["replica_restarts"] == 1 and f["redriven_requests"] == 4

@@ -7,15 +7,16 @@ Anchor invariants:
   sublane tile, launch ``qmatmul_pallas`` / ``qkv_pallas`` /
   ``qmlp_pallas`` with the decode block rule, and match the jnp
   ``grouped`` sequence (interpret mode on CPU).
-* The decode rule admits yi-9b's decode shapes (D 4096, kv 512, FF 11008
-  = 43 x 256, group 128, int8 and int4) with blocks that stream the
-  weight in large tiles.
+* The benchmark's configurations (yi-9b, moonlight-16b at published
+  widths, int8 and int4) take the recorded kernels with the recorded
+  blocks at every slot count and prompt length their cells run.
 * Every M >= 128 decision is the prefill gate's (``_pallas_aligned``)
   with the prefill blocks, unchanged.
 * A trace sharded over a mesh never takes a kernel.
 * ``ewq_qmatmul_calls_total`` counts each dispatch by path and regime.
 """
 
+import dataclasses
 import functools
 import types
 
@@ -25,10 +26,14 @@ import numpy as np
 import pytest
 
 from repro import obs
+from repro.configs.registry import get_config
+from repro.core.policy import BlockDecision, QuantPlan
 from repro.kernels.qmatmul import kernel as K
 from repro.kernels.qmatmul import ops
 from repro.kernels.qmatmul.kernel import DEFAULT_BK, DEFAULT_BM, DEFAULT_BN
 from repro.kernels.qmatmul.ops import fused_mlp, fused_qkv, qdot
+from repro.models.model import build
+from repro.quant.compiler import plan_length
 from repro.quant.qtypes import QTensor
 from repro.quant.quantize import quantize_int4, quantize_int8
 from repro.sharding.ctx import activation_sharding
@@ -146,57 +151,109 @@ def test_fused_mlp_decode_kernel_matches_grouped(interpret_kernels,
 # dispatch
 # ---------------------------------------------------------------------------
 
-YI = dict(d=4096, kv=512, ff=11008)
+# The chip cells' row counts: slot counts (decode) and prompt lengths
+# (prefill) of docqa and decode-batch (yi), longdoc-batch (moonlight).
+BENCH_CASES = (
+    [("yi-9b-12L", p, m) for p in ("int8", "int4")
+     for m in (16, 64, 128, 256, 512, 1024, 2048, 4096)]
+    + [("moonlight-16b-5L", p, m) for p in ("int8", "int4")
+       for m in (64, 1024, 2048, 4096)])
+# arch and depth traced: one layer of yi; moonlight's dense layer and one
+# MoE layer
+BENCH_ARCHS = {"yi-9b-12L": ("yi-9b", 1), "moonlight-16b-5L":
+               ("moonlight-16b", 2)}
 
 
-@pytest.mark.parametrize("precision", ["int8", "int4"])
-@pytest.mark.parametrize("m", [16, 64])
-def test_decode_rule_admits_yi_decode_shapes(recorded_kernels, precision, m):
-    d, kv, ff = YI["d"], YI["kv"], YI["ff"]
-    x = jax.ShapeDtypeStruct((m, 1, d), jnp.bfloat16)
+def _bench_dispatch(config, precision, m):
+    """The kernels, (activation columns, blocks) in trace order, and the
+    ``ewq_qmatmul_calls_total`` counts by (path, regime) that the traced
+    layers (``BENCH_ARCHS``) take at ``m`` rows."""
+    int8 = precision == "int8"
+    if config == "yi-9b-12L" and m < 128:
+        return ([("qkv_pallas", 4096, {"bm": m, "bk": 1024 if int8
+                                       else 2048}),
+                 ("qmatmul_pallas", 4096, {"bm": m, "bn": 512,
+                                           "bk": 2048}),
+                 ("qmlp_pallas", 4096, {"act": "swiglu", "bm": m,
+                                        "bf": 256})],
+                {("pallas", "decode"): 3})
+    if config == "yi-9b-12L":
+        # wq/wk/wv, wo, w_gate, w_up; w_down (FF 11008 = 21.5 x 512) is jnp
+        return ([("qkv_pallas", 4096, {"bm": 256, "bk": 512})]
+                + [("qmatmul_pallas", 4096, {})] * 3,
+                {("pallas", "prefill"): 4, ("dequant", "prefill"): 1})
+    if m < 128:
+        # per layer wq, wo; the dense MLP (FF 11264), then the shared
+        # experts (FF 2816); wkv_a (576 rows) and wkv_b stay jnp
+        dec = {"bm": m, "bn": 512, "bk": 2048}
+        return ([("qmatmul_pallas", 2048, dec)] * 2
+                + [("qmlp_pallas", 2048, {"act": "swiglu", "bm": m,
+                                          "bf": 512})]
+                + [("qmatmul_pallas", 2048, dec)] * 2
+                + [("qmlp_pallas", 2048, {"act": "swiglu", "bm": m,
+                                          "bf": 256})],
+                {("pallas", "decode"): 6, ("dequant", "decode"): 4,
+                 ("dequant", "expert"): 3})
+    # prefill, per layer: wq, wkv_b (int8 only: int4 packs its K of 512
+    # into 256 lanes), wo; then the dense MLP's qmlp, or the shared
+    # experts' gate and up (their w_down, K 2816, is jnp)
+    attn = ([("qmatmul_pallas", 2048, {}), ("qmatmul_pallas", 512, {})]
+            if int8 else [("qmatmul_pallas", 2048, {})])
+    attn = attn + [("qmatmul_pallas", 2048, {})]
+    return (attn + [("qmlp_pallas", 2048, {"act": "swiglu", "bm": 256,
+                                           "bf": 256})]
+            + attn + [("qmatmul_pallas", 2048, {})] * 2,
+            {("pallas", "prefill"): 9 if int8 else 7,
+             ("dequant", "prefill"): 3 if int8 else 5,
+             ("dequant", "expert"): 3})
 
-    def layer(x, wq, wk, wv, wo, wg, wu, wd):
-        q, _, _ = fused_qkv(x, wq, wk, wv)
-        return qdot(q, wo) + fused_mlp(x, wg, wu, wd)
 
+@pytest.mark.parametrize("config,precision,m", BENCH_CASES)
+def test_benchmark_configs_take_the_same_kernels(recorded_kernels, config,
+                                                 precision, m):
+    """The benchmark's configurations, traced abstractly with every layer
+    at ``precision``: decode (``m`` slots) or prefill (one ``m``-token
+    prompt) launches the recorded kernels with the recorded blocks."""
+    arch, layers = BENCH_ARCHS[config]
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers)
+    model = build(cfg)
+    ds = [BlockDecision(block_index=i, exec_index=i + 1, entropy=0.0,
+                        num_parameters=0,
+                        precision="raw" if i == 0 else precision)
+          for i in range(plan_length(cfg))]
+    plan = QuantPlan(decisions=ds, mu=0, sigma=0, threshold=0, x_factor=1)
+    params = jax.eval_shape(lambda p: model.compile_plan(p, plan).params,
+                            model.abstract_params())
     with obs.capture() as (_, mx):
-        jax.eval_shape(layer, x, _spec(precision, d, d),
-                       _spec(precision, kv, d), _spec(precision, kv, d),
-                       _spec(precision, d, d), _spec(precision, ff, d),
-                       _spec(precision, ff, d), _spec(precision, d, ff))
-    qkv_bk = 2048 if precision == "int4" else 1024
+        if m < ops.DECODE_M:
+            cache = jax.eval_shape(lambda: model.init_cache(m, 64))
+            jax.eval_shape(model.decode_step, params, cache,
+                           jax.ShapeDtypeStruct((m, 1), jnp.int32))
+        else:
+            jax.eval_shape(lambda p, t: model.apply(
+                p, {"tokens": t}, remat=False, last_only=True), params,
+                jax.ShapeDtypeStruct((1, m), jnp.int32))
+    calls, counts = _bench_dispatch(config, precision, m)
     assert recorded_kernels == [
-        ("qkv_pallas", (m, d), {"group": 128, "precision": precision,
-                                "bm": m, "bk": qkv_bk}),
-        ("qmatmul_pallas", (m, d), {"group": 128, "precision": precision,
-                                    "bm": m, "bn": 512, "bk": 2048}),
-        ("qmlp_pallas", (m, d), {"group": 128, "precision": precision,
-                                 "act": "swiglu", "bm": m, "bf": 256}),
-    ]
-    assert mx.counter(COUNTER).value(path="pallas", regime="decode") == 3
-    assert mx.counter(COUNTER).value(path="dequant", regime="decode") is None
+        (name, (m, k), {"group": 128, "precision": precision, **blocks})
+        for name, k, blocks in calls]
+    c = mx.counter(COUNTER)
+    assert {(path, regime): c.value(path=path, regime=regime)
+            for path in ("pallas", "dequant")
+            for regime in ("decode", "prefill", "expert")
+            if c.value(path=path, regime=regime)} == counts
 
 
 def test_decode_rule_pads_m_and_keeps_configured_blocks(recorded_kernels):
+    """M pads to the sublane tile; the blocks are the decode rule's
+    constants, the first that fit the shape."""
     x = jax.ShapeDtypeStruct((3, 1024), jnp.bfloat16)
     out = jax.eval_shape(lambda x, w: qdot(x, w), x,
                          _spec("int8", 1024, 1024))
     assert out.shape == (3, 1024)
-    ops.configure_qmatmul(bn=256, bk=256)
-    try:
-        jax.eval_shape(lambda x, w: qdot(x, w), x, _spec("int8", 1024, 1024))
-        # a configured block that does not divide the shape is not used
-        ops.configure_qmatmul(bn=384, bk=384)
-        jax.eval_shape(lambda x, w: qdot(x, w), x, _spec("int8", 1024, 1024))
-    finally:
-        ops._blocks.update(bm=None, bn=None, bk=None)
     assert [c[1:] for c in recorded_kernels] == [
-        ((16, 1024), {"group": 128, "precision": "int8", "bm": 16,
-                      "bn": 512, "bk": 1024}),
-        ((16, 1024), {"group": 128, "precision": "int8", "bm": 16,
-                      "bn": 256, "bk": 256}),
-        ((16, 1024), {"group": 128, "precision": "int8", "bm": 16,
-                      "bn": 512, "bk": 1024}),
+        ((ops.SUBLANE, 1024), {"group": 128, "precision": "int8",
+                               "bm": ops.SUBLANE, "bn": 512, "bk": 1024}),
     ]
 
 
@@ -253,24 +310,6 @@ def test_prefill_decisions_are_the_aligned_gate(recorded_kernels, m, n, k,
             want.append(("qmatmul_pallas", (m, n),
                          {"group": 128, "precision": precision}))
     assert recorded_kernels == want
-
-
-def test_prefill_takes_configured_blocks(recorded_kernels):
-    x = jax.ShapeDtypeStruct((256, 4096), jnp.bfloat16)
-    w = _spec("int8", 4096, 4096)
-    ops.configure_qmatmul(bm=128, bn=512, bk=1024)
-    try:
-        jax.eval_shape(lambda x, w: qdot(x, w), x, w)
-        jax.eval_shape(lambda x, w: fused_qkv(x, w, w, w), x, w)
-        jax.eval_shape(lambda x, w: fused_mlp(x, w, w, w), x, w)
-    finally:
-        ops._blocks.update(bm=None, bn=None, bk=None)
-    base = {"group": 128, "precision": "int8", "bm": 128}
-    assert [(c[0], c[2]) for c in recorded_kernels] == [
-        ("qmatmul_pallas", dict(base, bn=512, bk=1024)),
-        ("qkv_pallas", dict(base, bk=1024)),
-        ("qmlp_pallas", dict(base, act="swiglu", bf=512)),
-    ]
 
 
 def test_sharded_trace_never_takes_the_kernel(monkeypatch):

@@ -49,7 +49,7 @@ def test_decode_chunk_and_prefill_carry_every_scope(dense):
     cfg, model, params = dense
     plan = fastewq_metadata_plan(cfg, "8bit-mixed")
     engine = ServeEngine(model, params, max_seq=32, plan=plan,
-                         kv_precision="int8", autotune=False)
+                         kv_precision="int8")
     progs = engine.compile_programs(8, 2, chunk=2)
     decode, prefill = _paths(progs["decode"]), _paths(progs["prefill"])
     assert _scopes_in(decode) == set(TOP_SCOPES)
@@ -62,7 +62,7 @@ def test_decode_chunk_and_prefill_carry_every_scope(dense):
 def test_compile_programs_allocates_no_decode_state(dense):
     cfg, model, params = dense
     engine = ServeEngine(model, params, max_seq=32, paged=PagedConfig(
-        page_size=8), autotune=False)
+        page_size=8))
     engine.init_decode_state(2)
     pool = engine.pool
     progs = engine.compile_programs(8, 2, chunk=2)
@@ -89,7 +89,7 @@ def test_serving_programs_have_distinct_names(dense):
     """The decode chunk alone is ``jit_run``: the trace's per-program
     readings key on the module name."""
     cfg, model, params = dense
-    paged = ServeEngine(model, params, max_seq=32, autotune=False,
+    paged = ServeEngine(model, params, max_seq=32,
                         paged=PagedConfig(page_size=8), prefill_chunk=4)
     state = paged.init_decode_state(2)
     pools = {name: getattr(state.cache, name)
@@ -106,15 +106,14 @@ def test_serving_programs_have_distinct_names(dense):
         "gather": _module(paged._pool_gather_fn().lower(
             pools, row, jnp.int32(8))),
     }
-    spec = ServeEngine(model, params, max_seq=32, autotune=False,
-                       spec=SpecConfig(k=2))
+    spec = ServeEngine(model, params, max_seq=32, spec=SpecConfig(k=2))
     sstate = spec.init_decode_state(2)
     names["spec"] = _module(spec._spec_fn(2).lower(
         spec.params, spec.draft_params, sstate))
     ecfg = get_config("whisper-medium", smoke=True)
     emodel = build(ecfg)
     enc = ServeEngine(emodel, emodel.init(jax.random.PRNGKey(0)),
-                      max_seq=32, autotune=False, prefill_chunk=4)
+                      max_seq=32, prefill_chunk=4)
     frames = enc._default_frames(1)
     enc._encdec_seed(frames)
     names["encdec"] = _module(enc._encdec_seed_fn.lower(enc.params, frames))

@@ -74,6 +74,14 @@ def test_fused_loop_matches_stepwise_greedy(tiny):
                                np.asarray(step.logprobs), atol=1e-4)
 
 
+def test_engine_refuses_autotune(tiny):
+    """Kernels are chosen from the platform and the shapes alone: the
+    engine takes no tuned configuration."""
+    cfg, model, params = tiny
+    with pytest.raises(ValueError, match="autotuner is gone"):
+        ServeEngine(model, params, max_seq=24, autotune=True)
+
+
 def test_slot_reuse_after_finish(tiny):
     """3 requests through 1 slot: each drains through the same slot and must
     match a dedicated single-request generate (insert fully resets state)."""
